@@ -36,7 +36,6 @@ import sys
 if __name__ == "__main__":                   # before any jax import
     os.environ.setdefault(
         "XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent))
 
 import numpy as np

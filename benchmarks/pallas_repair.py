@@ -3,65 +3,66 @@
 Races the two kernel regimes of ``PallasEngine`` on the paper's graph
 mix and lands every row in ``BENCH_pallas.json``:
 
-  relax    one fused launch (gather → relax → frontier-flag → in-kernel
-           compaction, ``kernels/pallas_repair.fused_relax_rows``) vs
+  relax    one fused launch over an XLA-side gather (relax → frontier
+           flag → in-kernel compaction,
+           ``kernels/pallas_repair.fused_relax_rows``) vs
            the chained rowmin → hit → rowargmin kernel chain
   spmv     fused SpMV+frontier launch vs the chained rowsum + segment_max
-  merge    ``update_csr_add`` with the merge-path pool kernel plugged in
-           vs the jnp binary-search + scatter rounds
   e2e      dynamic SSSP end to end on the ``pallas`` vs the
            ``pallas_chained`` registry engines
 
 Each row carries a *roofline-relative efficiency*: achieved bytes/s for
-a coarse traffic model of the launch (ELL arrays streamed once per
-launch, vertex arrays once, outputs once — the chained rows pay the
-re-stream per op) against ``roofline.HBM_BW``.  On the CPU interpret
+a coarse traffic model of the launch (the XLA gather and transposes
+that feed the kernel, its (K, R) tile reads, outputs once — the
+chained rows pay the gather per op) against ``roofline.HBM_BW``.  On the CPU interpret
 backend these fractions are tiny by construction; the quantity exists so
 the same JSON rows become meaningful when the suite runs on a real TPU,
 and so PRs can still compare fused-vs-chained *ratios* on CPU.
 """
 from __future__ import annotations
 
-import functools
-
 import numpy as np
-import jax
 import jax.numpy as jnp
 
 from common import timeit, emit, bench_graphs
 from roofline import HBM_BW
 from repro.graph import build_csr, random_updates
-from repro.graph import diffcsr
 from repro.graph.csr import INF_W
 from repro.core.registry import make_engine
-from repro.core.pallas_engine import _fused_upd_add
 from repro.kernels import ops as kops
-from repro.kernels import pallas_repair as FK
 from repro.algos import sssp
 
 _ITM = 4  # int32/float32 lanes throughout the repair path
 
-_upd_scatter = jax.jit(diffcsr.update_csr_add)
-
 
 def _relax_bytes(R, K, n, fused):
-    """Coarse HBM traffic model (bytes) for one repair sweep.
+    """Coarse HBM traffic model (bytes) for one relax sweep.
 
-    fused:   ell_src + ell_w once, vals once, min/arg/rows/counts out.
-    chained: rowmin and rowargmin each re-stream the ELL arrays and
-             vals, plus the hit pass over the vertex arrays.
+    Every launch first gathers vals[ell_src] in XLA (ell_src and vals
+    read, a (K, R) array written) and transposes each other ELL array
+    it takes to (K, R) (read + write); the kernel then reads its (K, R)
+    tiles once.
+    fused:   one gather, ell_w and ell_src transposed, three tile reads,
+             min/arg/rows out.
+    chained: rowmin (gather, ell_w transposed, two tile reads, one row
+             out), rowargmin (gather, both transposed, three tile reads,
+             targets in, one row out), then the hit pass.
     """
+    rk = R * K
+    gather = 2 * rk + (n + 1)
     if fused:
-        return _ITM * (2 * R * K + (n + 1) + 3 * R)
-    return _ITM * (2 * (2 * R * K + (n + 1) + R) + 2 * R)
+        return _ITM * (gather + 2 * 2 * rk + 3 * rk + 3 * R)
+    rowmin = gather + 2 * rk + 2 * rk + R
+    rowargmin = gather + 2 * 2 * rk + 3 * rk + 2 * R
+    return _ITM * (rowmin + rowargmin + 2 * R)
 
 
-def _merge_bytes(D, B, fused):
-    """fused: one merge-path pass (read pool+batch, write pool).
-    scatter: two searchsorted sweeps + scatter rounds ~ 3 pool passes."""
-    if fused:
-        return _ITM * (4 * D + 4 * B + 4 * D)
-    return _ITM * (3 * 2 * 4 * D + 4 * B)
+def _spmv_bytes(R, K, n, fused):
+    """The same model for a SpMV sweep: one gather and one (K, R) tile
+    read, row sums out; fused also reads row2dst and writes the
+    compacted rows."""
+    base = 2 * R * K + (n + 1) + R * K + R
+    return _ITM * (base + (2 * R if fused else 0))
 
 
 def _roofline(nbytes, us):
@@ -124,36 +125,12 @@ def run(small=True, quick=False, percent=5, batch=16, iters=2):
         t_f = timeit(spmv_fused, iters=iters)
         t_c = timeit(spmv_chained, iters=iters)
         for mode, t in (("fused", t_f), ("chained", t_c)):
-            nbytes = _relax_bytes(R, K, n, mode == "fused")
+            nbytes = _spmv_bytes(R, K, n, mode == "fused")
             gbps, frac = _roofline(nbytes, t)
             emit(f"pallas/spmv/{gname}/{mode}", t,
                  f"fused_speedup={t_c / max(t_f, 1):.2f};"
                  f"model_bytes={nbytes};gbps={gbps:.3f};"
                  f"roofline_frac={frac:.2e}")
-
-        # -- update merge: merge-path kernel vs scatter rounds -------------
-        b0 = ups.batch(0, batch)
-        g0, B, D = h.g, batch, h.g.diff_capacity
-        upd_fused = _fused_upd_add(True, cfg.merge_block)
-
-        def merge_fused():
-            return upd_fused(g0, b0.add_src, b0.add_dst, b0.add_w,
-                             b0.add_mask)
-
-        def merge_scatter():
-            return _upd_scatter(g0, b0.add_src, b0.add_dst, b0.add_w,
-                                b0.add_mask)
-
-        t_f = timeit(merge_fused, iters=iters)
-        t_c = timeit(merge_scatter, iters=iters)
-        for mode, t in (("fused", t_f), ("scatter", t_c)):
-            nbytes = _merge_bytes(D, B, mode == "fused")
-            gbps, frac = _roofline(nbytes, t)
-            emit(f"pallas/merge/{gname}/{mode}", t,
-                 f"fused_speedup={t_c / max(t_f, 1):.2f};"
-                 f"model_bytes={nbytes};gbps={gbps:.3f};"
-                 f"roofline_frac={frac:.2e};"
-                 f"pool={D};batch={B};merge_block={cfg.merge_block}")
 
         # -- end to end: the two registry engines race dynamic SSSP --------
         if quick:

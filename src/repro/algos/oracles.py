@@ -6,7 +6,7 @@ dynamic result must equal the oracle run on the post-update edge set
 """
 from __future__ import annotations
 
-from typing import Dict, List, Set, Tuple
+from typing import List, Set
 
 import numpy as np
 
@@ -15,20 +15,31 @@ INF = np.int64(np.iinfo(np.int32).max // 2)
 
 def edges_after_updates(n: int, edges: np.ndarray, weights: np.ndarray,
                         adds: np.ndarray, dels: np.ndarray):
-    """Apply Δ to an edge set host-side (dedup, delete-then-add per batch
-    order is irrelevant for the final set as adds are fresh edges)."""
-    ew: Dict[Tuple[int, int], int] = {}
-    for (u, v), w in zip(edges.tolist(), weights.tolist()):
-        ew[(u, v)] = w
-    for u, v in dels.tolist():
-        ew.pop((u, v), None)
-    for u, v, w in adds.tolist():
-        ew[(u, v)] = w
-    if not ew:
+    """Apply Δ to an edge set host-side: deletes before adds, the last
+    weight wins among duplicate base edges and among duplicate adds, and
+    the result is sorted by (src, dst).  Vectorized over int64 pair keys
+    so it runs at graph scale."""
+    edges = np.asarray(edges, np.int64).reshape(-1, 2)
+    weights = np.asarray(weights).reshape(-1)
+    adds = np.asarray(adds).reshape(-1, 3)
+    dels = np.asarray(dels, np.int64).reshape(-1, 2)
+    pairs = np.concatenate([edges, adds[:, :2].astype(np.int64), dels])
+    if not len(edges) + len(adds):
         return np.zeros((0, 2), np.int64), np.zeros((0,), np.int32)
-    e = np.array(sorted(ew), dtype=np.int64)
-    w = np.array([ew[tuple(x)] for x in e.tolist()], dtype=np.int32)
-    return e, w
+    lo = pairs.min()
+    span = pairs.max() - lo + 1
+
+    def key(p):
+        return (p[:, 0] - lo) * span + (p[:, 1] - lo)
+
+    base = key(edges)
+    kept = ~np.isin(base, key(dels))
+    k = np.concatenate([base[kept], key(adds[:, :2].astype(np.int64))])
+    w = np.concatenate([weights[kept], adds[:, 2]]).astype(np.int32)
+    # np.unique keeps the first occurrence: reverse so the LAST write wins
+    uk, first = np.unique(k[::-1], return_index=True)
+    e = np.stack([uk // span + lo, uk % span + lo], axis=1)
+    return e, w[::-1][first]
 
 
 def sssp_oracle(n: int, edges: np.ndarray, weights: np.ndarray,

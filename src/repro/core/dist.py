@@ -33,22 +33,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P, NamedSharding
 
-try:                                    # jax >= 0.5 re-exports at top level
-    from jax import shard_map as _shard_map
-except ImportError:                     # jax 0.4.x keeps it in experimental
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
-def shard_map(fn, *, mesh, in_specs, out_specs, check_vma=False, **kw):
-    """Version-tolerant shard_map: jax 0.4.x spells the VMA-check kwarg
-    ``check_rep``; newer releases renamed it ``check_vma``."""
-    try:
-        return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=check_vma, **kw)
-    except TypeError:
-        return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check_vma, **kw)
-
 from repro.core.ir import EdgeSweep, Reduce, trace_read_set
 from repro.core.engine import Engine, Collectives, Props, WedgeCtx, \
     edge_lane_flags, _STREAM_CACHE_LOCK
@@ -253,8 +237,8 @@ class DistEngine(Engine):
         return self._block
 
     def _shmap(self, fn, in_specs, out_specs):
-        return shard_map(fn, mesh=self.mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
+        return jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
 
     def _gspec(self):
         """Sharding spec for a stacked DistGraph pytree."""

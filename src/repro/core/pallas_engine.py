@@ -9,17 +9,19 @@ lowering (the paper, likewise, only kernelizes the forall bodies).
 Two kernel regimes, selected by the ``fused`` flag:
 
   * fused (default) — the repair step runs ONE launch per sweep
-    (``kernels/pallas_repair.fused_relax_rows``: gather → relax →
-    frontier-flag → in-kernel compaction), and ``update_add`` merges the
-    batch into the diff pool with the merge-path kernel instead of the
-    jnp scatter rounds.  Block sizes come from the (N, E_cap, K)-keyed
-    autotuner, cached per handle shape.
+    (``kernels/pallas_repair.fused_relax_rows``: relax → row min/argmin
+    → frontier-flag → in-kernel compaction, over a gather done in XLA).
+    Block sizes come from the (N, E_cap, K)-keyed autotuner, cached per
+    handle shape.
   * chained (``fused=False``, registry name ``pallas_chained``) — the
     original per-op kernel chain (rowmin → hit → rowargmin), kept as
     the benchmark baseline for BENCH_pallas.json.
 
 Both regimes are bit-exact against each other and the jnp lowering
-(tests/test_kernels.py, tests/test_conformance.py).
+(tests/test_kernels.py, tests/test_conformance.py).  ΔG merges into the
+diff pool run on the jnp lowering (``diffcsr.update_csr_add``) in both.
+Kernels compile on a TPU and run in the Pallas interpreter elsewhere
+(``kernels.platform.interpret_mode``).
 
 The ELL pack is rebuilt once per update batch and *reused across all
 fixed-point iterations* — the analogue of the paper's CUDA optimization
@@ -58,28 +60,13 @@ class PallasHandle:
 
 
 
-
-@functools.lru_cache(maxsize=None)
-def _fused_upd_add(interpret: bool, block: int):
-    """Jitted update_csr_add with the merge-path pool kernel plugged in.
-
-    Cached per (interpret, block) so every engine instance (and every
-    trace inside a fused stream scan) binds the SAME jitted callable —
-    jit's executable cache then keys only on the handle shapes."""
-    merge = functools.partial(FK.merge_pool_sorted, block=block,
-                              interpret=interpret)
-    return jax.jit(functools.partial(diffcsr.update_csr_add,
-                                     pool_merge=merge))
-
-
 class PallasEngine(JnpEngine):
     name = "pallas"
 
-    def __init__(self, k: int = 8, interpret: bool = True,
-                 fused: bool = True, autotune: bool = False):
+    def __init__(self, k: int = 8, fused: bool = True,
+                 autotune: bool = False):
         super().__init__()
         self.k = k
-        self.interpret = interpret
         self.fused = fused
         self.autotune = autotune     # measure candidates vs. heuristic
         # stable per-engine jitted repack: ell_apply_add's cond branch
@@ -89,7 +76,7 @@ class PallasEngine(JnpEngine):
     def _config(self, g: DynGraph) -> FK.RepairConfig:
         return FK.repair_config(
             g.n, g.main_capacity + g.diff_capacity, self.k,
-            measure=self.autotune, interpret=self.interpret)
+            measure=self.autotune)
 
     # -- construction / updates --------------------------------------------
     # The ELL pack stays device-resident across batches: tombstones and
@@ -135,21 +122,11 @@ class PallasEngine(JnpEngine):
         return PallasHandle(g=g, ell=ell)
 
     def update_add(self, h: PallasHandle, batch: UpdateBatch) -> PallasHandle:
-        # both regimes launch kernels here (fused: the merge-path pool
-        # fold; chained: the ELL pack update below), so the chaos seam
-        # sits above the branch — ctx carries `fused` for targeting
+        # the ELL pack update below may repack on the device, so the
+        # chaos seam sits here — ctx carries `fused` for targeting
         _faults.fire("kernel_launch", engine=self.name,
                      fused=self.fused, op="update_add")
-        if self.fused:
-            # one merge-path launch folds the admitted batch into the
-            # sorted diff pool (replaces two binary-search sweeps + four
-            # scatter rounds)
-            cfg = self._config(h.g)
-            g = _fused_upd_add(self.interpret, cfg.merge_block)(
-                h.g, batch.add_src, batch.add_dst, batch.add_w,
-                batch.add_mask)
-        else:
-            g = super().update_add(h.g, batch)
+        g = super().update_add(h.g, batch)
         # pull layout: slots hold SOURCES
         ell = ell_apply_add(h.ell, h.g, g, batch.add_src, batch.add_dst,
                             batch.add_w, batch.add_mask,
@@ -215,14 +192,12 @@ class PallasEngine(JnpEngine):
             if red.kind == "min":
                 assert use_w
                 vmin, parent, hv = kops.vertex_relax_fused(
-                    ell, vals_n1, block=cfg.row_block,
-                    interpret=self.interpret)
+                    ell, vals_n1, block=cfg.row_block)
                 reduced[target], hit[target] = vmin, hv
                 parents[target] = parent
             else:  # sum
                 vsum, hv = kops.vertex_spmv_fused(
-                    ell, vals_n1, block=cfg.row_block,
-                    interpret=self.interpret)
+                    ell, vals_n1, block=cfg.row_block)
                 reduced[target], hit[target] = vsum, hv
         for target, red in sw.reduces.items():
             if red.kind != "argmin":
@@ -248,11 +223,10 @@ class PallasEngine(JnpEngine):
             vals_n1 = jnp.concatenate([vec, jnp.full((1,), ident, vec.dtype)])
             if red.kind == "min":
                 assert use_w
-                reduced[target] = kops.vertex_min_plus(
-                    ell, vals_n1, interpret=self.interpret)
+                reduced[target] = kops.vertex_min_plus(ell, vals_n1)
                 hit[target] = reduced[target] < ident
             else:  # sum
-                r = kops.vertex_spmv(ell, vals_n1, interpret=self.interpret)
+                r = kops.vertex_spmv(ell, vals_n1)
                 reduced[target] = r
                 hit[target] = jax.ops.segment_max(
                     (ell.row2dst < n).astype(INT),
@@ -267,8 +241,8 @@ class PallasEngine(JnpEngine):
             vec = vec_fn(props)
             vals_n1 = jnp.concatenate(
                 [vec, jnp.full((1,), INF_W, vec.dtype)])
-            reduced[target] = kops.vertex_argmin_src(
-                ell, vals_n1, reduced[of], interpret=self.interpret)
+            reduced[target] = kops.vertex_argmin_src(ell, vals_n1,
+                                                     reduced[of])
             hit[target] = hit[of]
         return sw.post_fn(props, reduced, hit)
 

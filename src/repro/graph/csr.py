@@ -64,8 +64,8 @@ def build_csr(n: int, edges: np.ndarray, weights: np.ndarray | None = None,
     src = edges[:, 0].astype(np.int32)
     dst = edges[:, 1].astype(np.int32)
     offsets = np.zeros(n + 1, dtype=np.int32)
-    np.add.at(offsets, src + 1, 1)
-    offsets = np.cumsum(offsets, dtype=np.int32)
+    offsets[1:] = np.cumsum(np.bincount(src, minlength=n)[:n],
+                            dtype=np.int32)
     return CSR(n=n, offsets=jnp.asarray(offsets), src=jnp.asarray(src),
                dst=jnp.asarray(dst), w=jnp.asarray(weights))
 
@@ -113,15 +113,18 @@ def rmat_graph(n_log2: int, avg_deg: int, seed: int = 0,
     m = n * avg_deg
     srcs = np.zeros(m, dtype=np.int64)
     dsts = np.zeros(m, dtype=np.int64)
+    r = np.empty(m)
+    r2 = np.empty(m)
     for bit in range(n_log2):
-        r = rng.random(m)
         # quadrant probabilities: a (0,0), b (0,1), c (1,0), d (1,1)
-        src_bit = (r >= a + b).astype(np.int64)
-        r2 = rng.random(m)
-        dst_bit = np.where(src_bit == 0, (r2 >= a / (a + b)).astype(np.int64),
-                           (r2 >= c / (1 - a - b)).astype(np.int64))
-        srcs = (srcs << 1) | src_bit
-        dsts = (dsts << 1) | dst_bit
+        rng.random(out=r)
+        src_bit = r >= a + b
+        rng.random(out=r2)
+        dst_bit = np.where(src_bit, r2 >= c / (1 - a - b), r2 >= a / (a + b))
+        srcs <<= 1
+        srcs |= src_bit
+        dsts <<= 1
+        dsts |= dst_bit
     edges = np.stack([srcs, dsts], axis=1)
     w = rng.integers(1, max_w, size=m).astype(np.int32)
     return n, edges, w

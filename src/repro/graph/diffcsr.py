@@ -228,14 +228,7 @@ def update_csr_del(g: DynGraph, del_src: jax.Array, del_dst: jax.Array,
 
 def update_csr_add(g: DynGraph, add_src: jax.Array, add_dst: jax.Array,
                    add_w: jax.Array | None = None,
-                   mask: jax.Array | None = None, *,
-                   pool_merge=None) -> DynGraph:
-    """``pool_merge`` plugs a backend merge kernel into step 3: called as
-    ``pool_merge(d_src, d_dst, d_w, d_alive, f_src, f_dst, f_w, f_alive,
-    n=g.n)`` with both lists sorted by (src, dst) and sentinel rows
-    (src == n) sunk to the end, it must return the merged
-    ``(d_src, d_dst, d_w, d_alive)`` — bit-exact against the default
-    scatter path (the Pallas backend passes its merge-path kernel)."""
+                   mask: jax.Array | None = None) -> DynGraph:
     add_src = jnp.asarray(add_src, INT)
     add_dst = jnp.asarray(add_dst, INT)
     if add_w is None:
@@ -291,37 +284,28 @@ def update_csr_add(g: DynGraph, add_src: jax.Array, add_dst: jax.Array,
         # compact the admitted fresh edges into a sorted (B,)-padded list
         f_src = jnp.full((B,), g.n, INT)
         f_dst = jnp.zeros((B,), INT)
-        f_w = jnp.zeros((B,), INT)
         ftgt = jnp.where(fits, fresh_rank, B)
         f_src = f_src.at[ftgt].set(s_src, mode="drop")
         f_dst = f_dst.at[ftgt].set(s_dst, mode="drop")
-        f_w = f_w.at[ftgt].set(s_w, mode="drop")
-        if pool_merge is not None:
-            # admitted ranks are a dense prefix, so alive = prefix mask
-            f_alive = jnp.arange(B, dtype=INT) < jnp.sum(fits.astype(INT))
-            d_src, d_dst, d_wn, d_al = pool_merge(
-                g.d_src, g.d_dst, d_w, d_alive, f_src, f_dst, f_w,
-                f_alive, n=g.n)
-        else:
-            # merged position of each existing pool row / each admitted
-            # edge.  Fresh edges are never equal to a materialized pool
-            # key (they would have been revivals), so ties cannot occur.
-            cnt_f = _pair_searchsorted(f_src, f_dst, g.d_src, g.d_dst,
-                                       _log2_iters(B))
-            cnt_p = _pair_searchsorted(g.d_src, g.d_dst, s_src, s_dst,
-                                       _log2_iters(d))
-            pool_rows = (g.d_src < g.n)
-            pool_pos = jnp.where(pool_rows,
-                                 jnp.arange(d, dtype=INT) + cnt_f, d)
-            fresh_pos = jnp.where(fits, fresh_rank + cnt_p, d)
-            d_src = jnp.full((d,), g.n, INT).at[pool_pos].set(
-                g.d_src, mode="drop").at[fresh_pos].set(s_src, mode="drop")
-            d_dst = jnp.zeros((d,), INT).at[pool_pos].set(
-                g.d_dst, mode="drop").at[fresh_pos].set(s_dst, mode="drop")
-            d_wn = jnp.zeros((d,), INT).at[pool_pos].set(
-                d_w, mode="drop").at[fresh_pos].set(s_w, mode="drop")
-            d_al = jnp.zeros((d,), BOOL).at[pool_pos].set(
-                d_alive, mode="drop").at[fresh_pos].set(True, mode="drop")
+        # merged position of each existing pool row / each admitted
+        # edge.  Fresh edges are never equal to a materialized pool
+        # key (they would have been revivals), so ties cannot occur.
+        cnt_f = _pair_searchsorted(f_src, f_dst, g.d_src, g.d_dst,
+                                   _log2_iters(B))
+        cnt_p = _pair_searchsorted(g.d_src, g.d_dst, s_src, s_dst,
+                                   _log2_iters(d))
+        pool_rows = (g.d_src < g.n)
+        pool_pos = jnp.where(pool_rows,
+                             jnp.arange(d, dtype=INT) + cnt_f, d)
+        fresh_pos = jnp.where(fits, fresh_rank + cnt_p, d)
+        d_src = jnp.full((d,), g.n, INT).at[pool_pos].set(
+            g.d_src, mode="drop").at[fresh_pos].set(s_src, mode="drop")
+        d_dst = jnp.zeros((d,), INT).at[pool_pos].set(
+            g.d_dst, mode="drop").at[fresh_pos].set(s_dst, mode="drop")
+        d_wn = jnp.zeros((d,), INT).at[pool_pos].set(
+            d_w, mode="drop").at[fresh_pos].set(s_w, mode="drop")
+        d_al = jnp.zeros((d,), BOOL).at[pool_pos].set(
+            d_alive, mode="drop").at[fresh_pos].set(True, mode="drop")
         d_offsets = jnp.searchsorted(d_src, jnp.arange(g.n + 1, dtype=INT),
                                      side="left").astype(INT)
     else:

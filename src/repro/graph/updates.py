@@ -128,16 +128,25 @@ def random_updates(csr: CSR, percent: float, seed: int = 0,
     del_idx = rng.choice(e, size=min(n_del, e), replace=False)
     dels = np.stack([src[del_idx], dst[del_idx]], axis=1).astype(np.int32)
 
-    # Fresh edges: sample, then drop collisions with existing edges.
-    existing = set(zip(src.tolist(), dst.tolist()))
+    # Fresh edges: sample, then drop collisions with existing edges and
+    # self-loops; within a draw the first copy of a key wins.  Membership
+    # runs on sorted int64 pair keys, so this scales to large graphs.
+    existing = np.sort(src.astype(np.int64) * n + dst)
+    taken = np.zeros((0,), np.int64)
     adds = []
     while len(adds) < n_add:
         cand = rng.integers(0, n, size=(2 * (n_add - len(adds)) + 8, 2))
-        for u, v in cand:
-            if (u, v) not in existing and u != v:
-                adds.append((int(u), int(v), int(rng.integers(1, max_w))))
-                existing.add((int(u), int(v)))
-                if len(adds) >= n_add:
-                    break
+        key = cand[:, 0] * np.int64(n) + cand[:, 1]
+        pos = np.minimum(np.searchsorted(existing, key), len(existing) - 1)
+        ok = (cand[:, 0] != cand[:, 1]) & ~np.isin(key, taken)
+        if len(existing):
+            ok &= existing[pos] != key
+        first = np.zeros(len(key), bool)
+        first[np.unique(key, return_index=True)[1]] = True
+        pick = np.flatnonzero(ok & first)[: n_add - len(adds)]
+        for i in pick:     # one weight draw per accepted edge, in order
+            adds.append((int(cand[i, 0]), int(cand[i, 1]),
+                         int(rng.integers(1, max_w))))
+        taken = np.concatenate([taken, key[pick]])
     adds = np.asarray(adds, dtype=np.int32).reshape(-1, 3)
     return UpdateStream(adds=adds, dels=dels)

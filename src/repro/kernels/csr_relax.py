@@ -1,28 +1,25 @@
 """Pallas TPU kernels for the paper's hot loop: edge relaxation over ELL.
 
-Three kernels, all the same shape discipline:
+Three kernels, all the same shape discipline as the fused ones in
+``pallas_repair`` (gather in XLA, transposed (K, R) slot tiles, rows on
+lanes, lane-dense (1, R) outputs):
 
   grid = (R // ROW_TILE,)
-  in:  ell_src (ROW_TILE, K) VMEM tile          — gathered indices
-       ell_w   (ROW_TILE, K) VMEM tile          — edge weights
-       vals    (n+1,)        full VMEM residency — property vector,
-                                                   identity at slot n
-  out: (ROW_TILE,) per-row combined value
+  in:  vals[ell_src]ᵀ (K, ROW_TILE) tile   — gathered in XLA
+       ell_wᵀ / ell_srcᵀ (K, ROW_TILE) tiles
+  out: (1, ROW_TILE) per-row combined value
 
 ``relax_rowmin``   : out[r] = min_k  vals[src[r,k]] + w[r,k]   (SSSP)
 ``spmv_rowsum``    : out[r] = sum_k  vals[src[r,k]]            (PageRank)
 ``relax_rowargmin``: out[r] = min_k  {src | vals[src]+w == target[row2dst]}
                      (deterministic parent selection for SSSP)
 
-The MXU plays no role here (no contractions); these are VPU kernels whose
-win is VMEM residency of the property vector across the whole row tile —
-the TPU reinterpretation of the paper's "CUDA kernel with per-edge
-threads + atomics".  Cross-row combination back to vertices is a cheap
-segment reduction outside the kernel (rows ≪ edges after packing).
+The MXU plays no role here (no contractions); these are VPU kernels.
+Cross-row combination back to vertices is a cheap segment reduction
+outside the kernel (rows ≪ edges after packing).
 
-Hardware alignment: ROW_TILE=128 rows (lane width), K defaults to 8 so a
-tile is 128×8 int32 = 4 KiB per operand; the vals vector is the dominant
-VMEM tenant (n+1 ints), sized by the caller to fit (≤ ~2M vertices).
+Hardware alignment: ROW_TILE=128 rows fill the lane width; K defaults
+to 8, one int32 sublane tile, so a (K, 128) tile is 4 KiB per operand.
 """
 from __future__ import annotations
 
@@ -30,81 +27,49 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-from repro.graph.csr import INF_W, INT
-
-ROW_TILE = 128
+from repro.kernels.pallas_repair import (ROW_TILE, fold_rows, row_argmin,
+                                         row_call)
 
 
-def _rowmin_kernel(src_ref, w_ref, vals_ref, out_ref):
-    s = src_ref[...]                      # (T, K) int32
-    w = w_ref[...]
-    gathered = vals_ref[s]                # gather from VMEM-resident vector
-    cand = gathered + w
-    out_ref[...] = jnp.min(cand, axis=1)
+def _rowmin_kernel(gv_ref, w_ref, out_ref):
+    out_ref[...] = fold_rows(gv_ref[...] + w_ref[...], jnp.minimum)
 
 
-def _rowsum_kernel(src_ref, vals_ref, out_ref):
-    s = src_ref[...]
-    out_ref[...] = jnp.sum(vals_ref[s], axis=1)
+def _rowsum_kernel(gv_ref, out_ref):
+    out_ref[...] = fold_rows(gv_ref[...], jnp.add)
 
 
-def _rowargmin_kernel(src_ref, w_ref, vals_ref, tgt_ref, out_ref, *, n):
-    s = src_ref[...]
-    w = w_ref[...]
-    cand = vals_ref[s] + w
-    achieved = cand == tgt_ref[...][:, None]
-    out_ref[...] = jnp.min(jnp.where(achieved, s, n), axis=1)
-
-
-def _grid_specs(R, K, n1, extra_rows=0):
-    row_spec = pl.BlockSpec((ROW_TILE, K), lambda i: (i, 0))
-    vec_spec = pl.BlockSpec((n1,), lambda i: (0,))
-    out_spec = pl.BlockSpec((ROW_TILE,), lambda i: (i,))
-    return row_spec, vec_spec, out_spec
+def _rowargmin_kernel(gv_ref, w_ref, src_ref, tgt_ref, out_ref, *, n):
+    out_ref[...] = row_argmin(gv_ref[...] + w_ref[...], src_ref[...],
+                              tgt_ref[...], n)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def relax_rowmin(ell_src, ell_w, vals, *, interpret=True):
+def relax_rowmin(ell_src, ell_w, vals, *, interpret=None):
     """vals: (n+1,) int32 with identity INF_W at slot n."""
     R, K = ell_src.shape
-    row_spec, vec_spec, out_spec = _grid_specs(R, K, vals.shape[0])
-    return pl.pallas_call(
-        _rowmin_kernel,
-        grid=(R // ROW_TILE,),
-        in_specs=[row_spec, row_spec, vec_spec],
-        out_specs=out_spec,
-        out_shape=jax.ShapeDtypeStruct((R,), vals.dtype),
-        interpret=interpret,
-    )(ell_src, ell_w, vals)
+    (out,) = row_call(_rowmin_kernel, K, R, ROW_TILE, 2, (vals.dtype,),
+                      interpret, vals[ell_src.T], ell_w.T)
+    return out[0]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def spmv_rowsum(ell_src, vals, *, interpret=True):
+def spmv_rowsum(ell_src, vals, *, interpret=None):
     """vals: (n+1,) float32 with 0.0 at slot n."""
     R, K = ell_src.shape
-    row_spec, vec_spec, out_spec = _grid_specs(R, K, vals.shape[0])
-    return pl.pallas_call(
-        _rowsum_kernel,
-        grid=(R // ROW_TILE,),
-        in_specs=[row_spec, vec_spec],
-        out_specs=out_spec,
-        out_shape=jax.ShapeDtypeStruct((R,), vals.dtype),
-        interpret=interpret,
-    )(ell_src, vals)
+    (out,) = row_call(_rowsum_kernel, K, R, ROW_TILE, 1, (vals.dtype,),
+                      interpret, vals[ell_src.T])
+    return out[0]
 
 
 @functools.partial(jax.jit, static_argnames=("n", "interpret"))
-def relax_rowargmin(ell_src, ell_w, vals, row_targets, *, n, interpret=True):
+def relax_rowargmin(ell_src, ell_w, vals, row_targets, *, n,
+                    interpret=None):
     """row_targets: (R,) the already-combined per-row target value."""
     R, K = ell_src.shape
-    row_spec, vec_spec, out_spec = _grid_specs(R, K, vals.shape[0])
-    return pl.pallas_call(
-        functools.partial(_rowargmin_kernel, n=n),
-        grid=(R // ROW_TILE,),
-        in_specs=[row_spec, row_spec, vec_spec, out_spec],
-        out_specs=out_spec,
-        out_shape=jax.ShapeDtypeStruct((R,), ell_src.dtype),
-        interpret=interpret,
-    )(ell_src, ell_w, vals, row_targets)
+    (out,) = row_call(functools.partial(_rowargmin_kernel, n=n), K, R,
+                      ROW_TILE, 3, (ell_src.dtype,), interpret,
+                      vals[ell_src.T], ell_w.T, ell_src.T,
+                      row_targets[None, :])
+    return out[0]

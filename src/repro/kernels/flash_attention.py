@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.platform import interpret_mode
+
 F32 = jnp.float32
 NEG = -1e30
 
@@ -63,7 +65,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc, *,
 @functools.partial(jax.jit, static_argnames=("causal", "bq", "bk",
                                              "softcap", "interpret"))
 def flash_attention(q, k, v, *, causal=True, bq=128, bk=512, softcap=None,
-                    interpret=True):
+                    interpret=None):
     """q,k,v: (BH, S, dh) with kv heads already repeated; returns (BH,S,dh).
 
     Causal masking is block-exact (whole future blocks still execute but
@@ -93,5 +95,5 @@ def flash_attention(q, k, v, *, causal=True, bq=128, bk=512, softcap=None,
             pltpu.VMEM((bq,), F32),
             pltpu.VMEM((bq, dh), F32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(q, k, v)

@@ -19,30 +19,27 @@ def _combine_rows(row_vals, row2dst, n, kind, identity):
     return dense[:n]
 
 
-def vertex_min_plus(ell: Ell, vals_n1: jax.Array, *, interpret=True):
+def vertex_min_plus(ell: Ell, vals_n1: jax.Array):
     """out[v] = min over in-edges (u,v) of vals[u] + w(u,v); INF if none."""
-    rows = K.relax_rowmin(ell.ell_src, ell.ell_w, vals_n1,
-                          interpret=interpret)
+    rows = K.relax_rowmin(ell.ell_src, ell.ell_w, vals_n1)
     return _combine_rows(rows, ell.row2dst, ell.n, "min",
                          jnp.asarray(INF_W, vals_n1.dtype))
 
 
-def vertex_spmv(ell: Ell, vals_n1: jax.Array, *, interpret=True):
+def vertex_spmv(ell: Ell, vals_n1: jax.Array):
     """out[v] = sum over in-edges (u,v) of vals[u]  (PageRank pull)."""
-    rows = K.spmv_rowsum(ell.ell_src, vals_n1, interpret=interpret)
+    rows = K.spmv_rowsum(ell.ell_src, vals_n1)
     return _combine_rows(rows, ell.row2dst, ell.n, "sum",
                          jnp.zeros((), vals_n1.dtype))
 
 
-def vertex_argmin_src(ell: Ell, vals_n1: jax.Array, vertex_min: jax.Array,
-                      *, interpret=True):
+def vertex_argmin_src(ell: Ell, vals_n1: jax.Array, vertex_min: jax.Array):
     """Smallest source achieving vertex_min[v] (deterministic parent)."""
     n = ell.n
     tgt_full = jnp.concatenate([vertex_min,
                                 jnp.full((1,), INF_W, vertex_min.dtype)])
     row_tgt = tgt_full[jnp.minimum(ell.row2dst, n)]
-    rows = K.relax_rowargmin(ell.ell_src, ell.ell_w, vals_n1, row_tgt,
-                             n=n, interpret=interpret)
+    rows = K.relax_rowargmin(ell.ell_src, ell.ell_w, vals_n1, row_tgt, n=n)
     return _combine_rows(rows, ell.row2dst, ell.n, "min",
                          jnp.asarray(n, INT))
 
@@ -62,8 +59,7 @@ def _frontier_hit(ell: Ell, front_rows: jax.Array) -> jax.Array:
         True, mode="drop")[:n]
 
 
-def vertex_relax_fused(ell: Ell, vals_n1: jax.Array, *, block=None,
-                       interpret=True):
+def vertex_relax_fused(ell: Ell, vals_n1: jax.Array, *, block=None):
     """(vertex_min, parent, hit) from ONE fused relax launch.
 
     Bit-exact against the chained vertex_min_plus → hit →
@@ -72,9 +68,8 @@ def vertex_relax_fused(ell: Ell, vals_n1: jax.Array, *, block=None,
     achieving the vertex min contribute the sentinel n), and a vertex
     is hit iff one of its rows improved on the identity."""
     n = ell.n
-    row_min, row_arg, front_rows, _ = FK.fused_relax_rows(
-        ell.ell_src, ell.ell_w, vals_n1,
-        block=block or FK.ROW_TILE, interpret=interpret)
+    row_min, row_arg, front_rows = FK.fused_relax_rows(
+        ell.ell_src, ell.ell_w, vals_n1, block=block or FK.ROW_TILE)
     seg = jnp.minimum(ell.row2dst, n)
     vmin = jax.ops.segment_min(row_min, seg, num_segments=n + 1)[:n]
     tgt_full = jnp.concatenate([vmin,
@@ -85,14 +80,12 @@ def vertex_relax_fused(ell: Ell, vals_n1: jax.Array, *, block=None,
     return vmin, parent, _frontier_hit(ell, front_rows)
 
 
-def vertex_spmv_fused(ell: Ell, vals_n1: jax.Array, *, block=None,
-                      interpret=True):
+def vertex_spmv_fused(ell: Ell, vals_n1: jax.Array, *, block=None):
     """(vertex_sum, hit) from one fused SpMV launch; hit marks vertices
     owning a materialized ELL row (the chained path's segment_max)."""
     n = ell.n
-    row_sum, front_rows, _ = FK.fused_spmv_rows(
-        ell.ell_src, ell.row2dst, vals_n1,
-        block=block or FK.ROW_TILE, interpret=interpret)
+    row_sum, front_rows = FK.fused_spmv_rows(
+        ell.ell_src, ell.row2dst, vals_n1, block=block or FK.ROW_TILE)
     seg = jnp.minimum(ell.row2dst, n)
     vsum = jax.ops.segment_sum(row_sum, seg, num_segments=n + 1)[:n]
     return vsum, _frontier_hit(ell, front_rows)

@@ -6,28 +6,33 @@ Two kernels replace the per-op chains in the Pallas backend:
 previously took three (rowmin → hit → rowargmin):
 
   grid = (R // block,)
-  in:  ell_src / ell_w (block, K) VMEM tiles, vals (n+1,) full residency
-       with the reduction identity at slot n
+  in:  gathered (K, block) tile of vals[ell_src]ᵀ (the gather runs in
+       XLA before the launch), ell_wᵀ / ell_srcᵀ (K, block) tiles, and
+       the reduction identity as an SMEM scalar
   out: row_min  (R,)   min_k vals[src] + w        per row
        row_arg  (R,)   min_k {src | cand == row_min}  (deterministic)
        rows     (R,)   in-tile compacted ids of frontier rows
                        (row_min < identity), sentinel R past each
-                       tile's count — the frontier is ready for a
-                       scatter without re-scanning R rows
-       counts   (R // block,) per-tile frontier sizes
+                       tile's frontier — ready for a scatter without
+                       re-scanning R rows
 
 ``fused_spmv_rows`` is the same fusion for sum-combining sweeps
 (PageRank): row sums + compacted materialized-row frontier.
 
-``merge_pool_sorted`` — the ΔG sorted-merge for ``update_csr_add``:
-merges the sorted diff pool (vacant rows src == n sunk to the end)
-with the sorted admitted batch in ONE launch via a merge-path binary
-search per output slot (the two-list diagonal split), replacing the
-two ``_pair_searchsorted`` sweeps + four scatter rounds of the jnp
-path.  Ties take the pool side first; since a fresh edge equal to a
-materialized pool key would have been a revival, real ties only occur
-between vacant/padding sentinels, whose payloads are identical — the
-merged pool is bit-exact against the scatter path.
+TPU layout (what Mosaic accepts): the data-dependent gather
+``vals[ell_src]`` cannot run inside a TPU kernel ("Cannot do int
+indexing on TPU"), and a resident (n+1,) property vector would not fit
+VMEM at graph scale, so the wrappers gather in XLA and hand the kernel
+TRANSPOSED (K, R) tiles.  Rows then sit on the 128 lanes, the per-row
+reductions run over the K sublanes, and every output is a lane-dense
+(1, block) block of a (1, R) array.  In-tile compaction is a prefix
+count on the MXU (a triangular 0/1 matmul, exact in f32) followed by a
+one-hot select reduced over sublanes.
+
+Interpret mode follows the platform (``platform.interpret_mode``):
+compiled on a TPU, the Pallas interpreter everywhere else.  The ``interpret=``
+keyword exists only so compile checks can force ``False`` on a host
+without a chip.
 
 Block sizes come from a tiny autotuner keyed on (N, E_cap, K) and
 cached per handle shape: the heuristic picks the largest row block
@@ -43,196 +48,126 @@ import threading
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.graph.csr import INT
 from repro.kernels.ell import ell_capacity
+from repro.kernels.platform import interpret_mode
 
 ROW_TILE = 128
 
 
-def _iota(length: int) -> jax.Array:
-    # 1-D iota is not supported on TPU; build it 2-D and drop the axis.
-    return jax.lax.broadcasted_iota(jnp.int32, (length, 1), 0).reshape(length)
+def fold_rows(x: jax.Array, op) -> jax.Array:
+    """Combine a (K, T) tile over its K slot rows in a fixed left-to-right
+    order, keeping the (1, T) row layout — float sums are then identical
+    on every platform and in interpret mode."""
+    acc = x[0:1, :]
+    for k in range(1, x.shape[0]):
+        acc = op(acc, x[k:k + 1, :])
+    return acc
+
+
+def row_argmin(cand: jax.Array, src: jax.Array, target: jax.Array,
+               n: int) -> jax.Array:
+    """Smallest source among the slots whose candidate hits ``target``
+    (sentinel n when none does)."""
+    return fold_rows(jnp.where(cand == target, src, n), jnp.minimum)
+
+
+def row_call(kernel, K: int, R: int, bt: int, n_lead: int, outs,
+             interpret, *args, smem: int = 0):
+    """Launch ``kernel`` over (K, R) slot tiles: ``smem`` leading SMEM
+    scalars, ``n_lead`` (K, R) tiles, then (1, R) row vectors; every
+    output is a (1, R) row vector of the given dtypes."""
+    tile = pl.BlockSpec((K, bt), lambda i: (0, i))
+    row = pl.BlockSpec((1, bt), lambda i: (0, i))
+    scalar = pl.BlockSpec(memory_space=pltpu.SMEM)
+    n_rows = len(args) - smem - n_lead
+    return pl.pallas_call(
+        kernel,
+        grid=(R // bt,),
+        in_specs=[scalar] * smem + [tile] * n_lead + [row] * n_rows,
+        out_specs=[row] * len(outs),
+        out_shape=[jax.ShapeDtypeStruct((1, R), d) for d in outs],
+        interpret=interpret_mode(interpret),
+    )(*args)
 
 
 # ---------------------------------------------------------------------------
-# fused relax: gather → relax → frontier-flag → compact, one launch
+# fused relax: relax → row min/argmin → frontier flag → compact, one launch
 # ---------------------------------------------------------------------------
 
-def _fused_relax_kernel(src_ref, w_ref, vals_ref, min_ref, arg_ref,
-                        rows_ref, cnt_ref, *, n, bt, R):
-    s = src_ref[...]                       # (bt, K) int32
-    w = w_ref[...]
-    cand = vals_ref[s] + w                 # gather + relax
-    rmin = jnp.min(cand, axis=1)
+def _compact(hit: jax.Array, base, bt: int, R: int) -> jax.Array:
+    """(1, bt) row ids of the tile's ``hit`` rows packed to its prefix,
+    sentinel R after the last one."""
+    h = jnp.broadcast_to(hit.astype(jnp.float32), (8, bt)).T   # (bt, 8)
+    i0 = jax.lax.broadcasted_iota(jnp.int32, (bt, bt), 0)
+    i1 = jax.lax.broadcasted_iota(jnp.int32, (bt, bt), 1)
+    # inclusive prefix count of hits, as a column: lower-triangular 0/1
+    # matmul (integers ≤ bt accumulate exactly in f32)
+    incl = jnp.dot((i1 <= i0).astype(jnp.float32), h,
+                   preferred_element_type=jnp.float32)[:, 0:1]
+    slot = incl.astype(jnp.int32) - 1                          # (bt, 1)
+    land = (h[:, 0:1] > 0) & (slot == i1)     # row i lands in slot j
+    packed = jnp.sum(jnp.where(land, i0, 0), axis=0, keepdims=True)
+    count = incl[bt - 1:bt, :].astype(jnp.int32)               # (1, 1)
+    j = jax.lax.broadcasted_iota(jnp.int32, (1, bt), 1)
+    return jnp.where(j < count, base + packed, R)
+
+
+def _fused_relax_kernel(ident_ref, gv_ref, w_ref, src_ref, min_ref,
+                        arg_ref, rows_ref, *, n, bt, R):
+    cand = gv_ref[...] + w_ref[...]        # (K, bt) relax
+    rmin = fold_rows(cand, jnp.minimum)
     min_ref[...] = rmin
     # deterministic per-row parent: smallest src achieving the row min
-    arg_ref[...] = jnp.min(jnp.where(cand == rmin[:, None], s, n), axis=1)
-    # frontier flag: the row improved on the identity at sentinel slot n
-    hit = rmin < vals_ref[n]
-    # in-tile compaction: frontier row ids packed to the tile's prefix
-    rowid = pl.program_id(0) * bt + _iota(bt)
-    pos = jnp.cumsum(hit.astype(jnp.int32)) - 1
-    rows_ref[...] = jnp.full((bt,), R, jnp.int32).at[
-        jnp.where(hit, pos, bt)].set(rowid, mode="drop")
-    cnt_ref[0] = jnp.sum(hit.astype(jnp.int32))
+    arg_ref[...] = row_argmin(cand, src_ref[...], rmin, n)
+    # frontier flag: the row improved on the identity
+    hit = rmin < ident_ref[0]
+    rows_ref[...] = _compact(hit, pl.program_id(0) * bt, bt, R)
 
 
-def _fused_spmv_kernel(src_ref, r2d_ref, vals_ref, sum_ref,
-                       rows_ref, cnt_ref, *, n, bt, R):
-    s = src_ref[...]
-    sum_ref[...] = jnp.sum(vals_ref[s], axis=1)
+def _fused_spmv_kernel(gv_ref, r2d_ref, sum_ref, rows_ref, *, n, bt, R):
+    sum_ref[...] = fold_rows(gv_ref[...], jnp.add)
     hit = r2d_ref[...] < n                 # materialized row for some vertex
-    rowid = pl.program_id(0) * bt + _iota(bt)
-    pos = jnp.cumsum(hit.astype(jnp.int32)) - 1
-    rows_ref[...] = jnp.full((bt,), R, jnp.int32).at[
-        jnp.where(hit, pos, bt)].set(rowid, mode="drop")
-    cnt_ref[0] = jnp.sum(hit.astype(jnp.int32))
-
-
-def _fused_specs(R, K, n1, bt):
-    row_spec = pl.BlockSpec((bt, K), lambda i: (i, 0))
-    col_spec = pl.BlockSpec((bt,), lambda i: (i,))
-    vec_spec = pl.BlockSpec((n1,), lambda i: (0,))
-    cnt_spec = pl.BlockSpec((1,), lambda i: (i,))
-    return row_spec, col_spec, vec_spec, cnt_spec
+    rows_ref[...] = _compact(hit, pl.program_id(0) * bt, bt, R)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def fused_relax_rows(ell_src, ell_w, vals, *, block=ROW_TILE, interpret=True):
-    """(row_min, row_arg, compacted frontier rows, per-tile counts)."""
+def fused_relax_rows(ell_src, ell_w, vals, *, block=ROW_TILE,
+                     interpret=None):
+    """(row_min, row_arg, compacted frontier rows); ``vals`` is (n+1,)
+    with the reduction identity at slot n."""
     R, K = ell_src.shape
     bt = block if R % block == 0 else ROW_TILE
     n = vals.shape[0] - 1
-    row_spec, col_spec, vec_spec, cnt_spec = _fused_specs(
-        R, K, vals.shape[0], bt)
-    return pl.pallas_call(
+    mins, args, rows = row_call(
         functools.partial(_fused_relax_kernel, n=n, bt=bt, R=R),
-        grid=(R // bt,),
-        in_specs=[row_spec, row_spec, vec_spec],
-        out_specs=[col_spec, col_spec, col_spec, cnt_spec],
-        out_shape=[jax.ShapeDtypeStruct((R,), vals.dtype),
-                   jax.ShapeDtypeStruct((R,), ell_src.dtype),
-                   jax.ShapeDtypeStruct((R,), jnp.int32),
-                   jax.ShapeDtypeStruct((R // bt,), jnp.int32)],
-        interpret=interpret,
-    )(ell_src, ell_w, vals)
+        K, R, bt, 3, (vals.dtype, ell_src.dtype, jnp.int32), interpret,
+        vals[n:], vals[ell_src.T], ell_w.T, ell_src.T, smem=1)
+    return mins[0], args[0], rows[0]
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def fused_spmv_rows(ell_src, row2dst, vals, *, block=ROW_TILE,
-                    interpret=True):
-    """(row_sum, compacted materialized rows, per-tile counts)."""
+                    interpret=None):
+    """(row_sum, compacted materialized rows)."""
     R, K = ell_src.shape
     bt = block if R % block == 0 else ROW_TILE
     n = vals.shape[0] - 1
-    row_spec, col_spec, vec_spec, cnt_spec = _fused_specs(
-        R, K, vals.shape[0], bt)
-    return pl.pallas_call(
+    sums, rows = row_call(
         functools.partial(_fused_spmv_kernel, n=n, bt=bt, R=R),
-        grid=(R // bt,),
-        in_specs=[row_spec, col_spec, vec_spec],
-        out_specs=[col_spec, col_spec, cnt_spec],
-        out_shape=[jax.ShapeDtypeStruct((R,), vals.dtype),
-                   jax.ShapeDtypeStruct((R,), jnp.int32),
-                   jax.ShapeDtypeStruct((R // bt,), jnp.int32)],
-        interpret=interpret,
-    )(ell_src, row2dst, vals)
+        K, R, bt, 1, (vals.dtype, jnp.int32), interpret,
+        vals[ell_src.T], row2dst[None, :])
+    return sums[0], rows[0]
 
 
 # ---------------------------------------------------------------------------
-# ΔG sorted-merge: diff pool + admitted batch, one merge-path launch
-# ---------------------------------------------------------------------------
-
-def _merge_iters(length: int) -> int:
-    it = 1
-    while (1 << it) < length + 1:
-        it += 1
-    return it + 1
-
-
-def _merge_kernel(ps_ref, pd_ref, pw_ref, pa_ref,
-                  fs_ref, fd_ref, fw_ref, fa_ref,
-                  os_ref, od_ref, ow_ref, oa_ref,
-                  *, n, D, B, bt, iters):
-    j = pl.program_id(0) * bt + _iota(bt)
-    # merge-path diagonal split: a = #fresh rows among the first j merged.
-    # Invariant P(a) = fresh[a] < pool[j-1-a] (strict: pool wins ties) is
-    # monotone in a; binary-search the first a where it fails.
-    lo = jnp.maximum(j - D, 0)
-    hi = jnp.minimum(j, B)
-
-    def body(_, carry):
-        lo, hi = carry
-        active = lo < hi
-        mid = (lo + hi) // 2
-        ms = fs_ref[jnp.clip(mid, 0, B - 1)]
-        md = fd_ref[jnp.clip(mid, 0, B - 1)]
-        pi = jnp.clip(j - 1 - mid, 0, D - 1)
-        qs = ps_ref[pi]
-        qd = pd_ref[pi]
-        less = (ms < qs) | ((ms == qs) & (md < qd))
-        lo = jnp.where(active & less, mid + 1, lo)
-        hi = jnp.where(active & ~less, mid, hi)
-        return lo, hi
-
-    a, _ = jax.lax.fori_loop(0, iters, body, (lo, hi))
-    ip = j - a
-    a_ok = a < B
-    p_ok = ip < D
-    a_safe = jnp.clip(a, 0, B - 1)
-    p_safe = jnp.clip(ip, 0, D - 1)
-    fs = fs_ref[a_safe]
-    fd = fd_ref[a_safe]
-    qs = ps_ref[p_safe]
-    qd = pd_ref[p_safe]
-    fresh_less = (fs < qs) | ((fs == qs) & (fd < qd))
-    take_f = a_ok & (~p_ok | fresh_less)
-    os_ref[...] = jnp.where(take_f, fs, jnp.where(p_ok, qs, n))
-    od_ref[...] = jnp.where(take_f, fd, jnp.where(p_ok, qd, 0))
-    ow_ref[...] = jnp.where(take_f, fw_ref[a_safe],
-                            jnp.where(p_ok, pw_ref[p_safe], 0))
-    oa_ref[...] = jnp.where(take_f, fa_ref[a_safe],
-                            jnp.where(p_ok, pa_ref[p_safe], 0))
-
-
-@functools.partial(jax.jit, static_argnames=("n", "block", "interpret"))
-def merge_pool_sorted(d_src, d_dst, d_w, d_alive, f_src, f_dst, f_w,
-                      f_alive, *, n, block=ROW_TILE, interpret=True):
-    """Merge the sorted diff pool with the sorted admitted batch.
-
-    Both lists are sorted by (src, dst) with sentinel rows (src == n,
-    dst == 0, w == 0, dead) at the end; returns the merged pool arrays
-    (d_src, d_dst, d_w, d_alive) with the same (D,) shape.
-    """
-    D = int(d_src.shape[0])
-    B = int(f_src.shape[0])
-    bt = min(block, ROW_TILE) if D < block else block
-    Dp = -(-D // bt) * bt
-    iters = _merge_iters(B)
-    pa = d_alive.astype(INT)
-    fa = f_alive.astype(INT)
-    full = lambda m: pl.BlockSpec((m,), lambda i: (0,))
-    out_spec = pl.BlockSpec((bt,), lambda i: (i,))
-    o_src, o_dst, o_w, o_al = pl.pallas_call(
-        functools.partial(_merge_kernel, n=n, D=D, B=B, bt=bt, iters=iters),
-        grid=(Dp // bt,),
-        in_specs=[full(D)] * 4 + [full(B)] * 4,
-        out_specs=[out_spec] * 4,
-        out_shape=[jax.ShapeDtypeStruct((Dp,), INT)] * 4,
-        interpret=interpret,
-    )(d_src, d_dst, d_w, pa, f_src, f_dst, f_w, fa)
-    return (o_src[:D], o_dst[:D], o_w[:D], o_al[:D].astype(jnp.bool_))
-
-
-# ---------------------------------------------------------------------------
-# autotuner: block sizes keyed on (N, E_cap, K), cached per handle shape
+# autotuner: row block keyed on (N, E_cap, K), cached per handle shape
 # ---------------------------------------------------------------------------
 
 @dataclasses.dataclass(frozen=True)
 class RepairConfig:
     row_block: int      # fused relax/spmv row tile (divides R)
-    merge_block: int    # merge-path output tile
 
 
 _TUNE_CACHE: dict = {}
@@ -242,7 +177,6 @@ _TUNE_CACHE: dict = {}
 # tuner authoritative.
 _TUNE_LOCK = threading.Lock()
 _ROW_CANDIDATES = (512, 256, 128)
-_MERGE_CANDIDATES = (256, 128)
 
 
 def clear_tune_cache() -> None:
@@ -250,15 +184,14 @@ def clear_tune_cache() -> None:
         _TUNE_CACHE.clear()
 
 
-def repair_config(n: int, e_cap: int, k: int, *, measure: bool = False,
-                  interpret: bool = True) -> RepairConfig:
+def repair_config(n: int, e_cap: int, k: int, *,
+                  measure: bool = False) -> RepairConfig:
     """Block config for a handle shape; one tuning per (N, E_cap, K)."""
     key = (int(n), int(e_cap), int(k))
     with _TUNE_LOCK:
         cfg = _TUNE_CACHE.get(key)
         if cfg is None:
-            cfg = (_measure_config(*key, interpret=interpret) if measure
-                   else _heuristic_config(*key))
+            cfg = _measure_config(*key) if measure else _heuristic_config(*key)
             _TUNE_CACHE[key] = cfg
     return cfg
 
@@ -272,12 +205,10 @@ def _heuristic_config(n: int, e_cap: int, k: int) -> RepairConfig:
         if R % cand == 0 and R // cand >= 2:
             row = cand
             break
-    merge = _MERGE_CANDIDATES[0] if e_cap >= 4096 else _MERGE_CANDIDATES[-1]
-    return RepairConfig(row_block=row, merge_block=merge)
+    return RepairConfig(row_block=row)
 
 
-def _measure_config(n: int, e_cap: int, k: int, *,
-                    interpret: bool) -> RepairConfig:
+def _measure_config(n: int, e_cap: int, k: int) -> RepairConfig:
     """Time the candidates on synthetic data of the keyed shape."""
     import numpy as np
     import timeit
@@ -290,29 +221,10 @@ def _measure_config(n: int, e_cap: int, k: int, *,
 
     def time_row(bt):
         run = lambda: jax.block_until_ready(fused_relax_rows(
-            src, w, vals, block=bt, interpret=interpret))
+            src, w, vals, block=bt))
         run()                                        # compile
         return min(timeit.repeat(run, number=1, repeat=3))
 
     rows = [bt for bt in _ROW_CANDIDATES if R % bt == 0 and R // bt >= 1] \
         or [ROW_TILE]
-    best_row = min(rows, key=time_row)
-
-    D = max(e_cap // 4, 16)
-    B = 64
-    ds = jnp.asarray(np.full(D, n, np.int32))
-    dz = jnp.zeros((D,), INT)
-    da = jnp.zeros((D,), jnp.bool_)
-    fs = jnp.asarray(np.sort(rng.integers(0, n, B)).astype(np.int32))
-    fz = jnp.zeros((B,), INT)
-    fa = jnp.ones((B,), jnp.bool_)
-
-    def time_merge(bt):
-        run = lambda: jax.block_until_ready(merge_pool_sorted(
-            ds, dz, dz, da, fs, fz, fz, fa, n=n, block=bt,
-            interpret=interpret))
-        run()
-        return min(timeit.repeat(run, number=1, repeat=3))
-
-    best_merge = min(_MERGE_CANDIDATES, key=time_merge)
-    return RepairConfig(row_block=best_row, merge_block=best_merge)
+    return RepairConfig(row_block=min(rows, key=time_row))

@@ -32,6 +32,7 @@ from repro.configs.archs import REGISTRY, get_arch
 from repro.configs.reduced import reduced
 from repro.models import transformer as T
 from repro.models.model import Model
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def serve(args) -> dict:
@@ -164,6 +165,7 @@ def parser():
 
 def main():
     args = parser().parse_args()
+    enable_compile_cache()
     if args.graph:
         serve_graphs(args)
     else:

@@ -344,7 +344,6 @@ def _moe_shard_map(p, x, cfg: ArchConfig, policy):
     of the global (E,C,D) buffer, and the expert flops are computed once
     (the jit lowering replicates them across the data axis).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mc = cfg.moe
@@ -386,11 +385,11 @@ def _moe_shard_map(p, x, cfg: ArchConfig, policy):
         return jax.lax.psum(y, policy.tp)            # combine over experts
 
     tp = policy.tp
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(dp or None, None), P(None, None),
                   P(tp, None, None), P(tp, None, None), P(tp, None, None)),
-        out_specs=P(dp or None, None), check_rep=False)
+        out_specs=P(dp or None, None), check_vma=False)
     xt = x.reshape(T, D)
     y = fn(xt, p["router"], p["w_gate"], p["w_up"], p["w_down"])
     return y.reshape(B, S, D)

@@ -43,7 +43,7 @@ from repro.core.ir import EdgeSweep
 from repro.core.engine import Engine, Props, edge_lane_flags, \
     _STREAM_CACHE_LOCK
 from repro.core.dist import DistEngine, DistGraph, DistCollectives, \
-    _DistStreamView, _DView, shard_map
+    _DistStreamView, _DView
 from repro.core import dist as _dist
 from repro.graph.csr import CSR, INT, build_csr
 from repro.graph import diffcsr

@@ -90,3 +90,45 @@ def test_propagate_flags():
     props = {"flag": jnp.array([True, False, False, False])}
     props = eng.propagate_flags(g, props, "flag")
     assert np.asarray(props["flag"]).tolist() == [True, True, True, False]
+
+
+def _edges_after_updates_dict(edges, weights, adds, dels):
+    """The plain dict semantics the vectorized oracle must keep."""
+    ew = {}
+    for (u, v), w in zip(edges.tolist(), weights.tolist()):
+        ew[(u, v)] = w
+    for u, v in dels.tolist():
+        ew.pop((u, v), None)
+    for u, v, w in adds.tolist():
+        ew[(u, v)] = w
+    if not ew:
+        return np.zeros((0, 2), np.int64), np.zeros((0,), np.int32)
+    e = np.array(sorted(ew), dtype=np.int64)
+    return e, np.array([ew[tuple(x)] for x in e.tolist()], dtype=np.int32)
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_edges_after_updates_matches_dict_semantics(seed):
+    """Deletes before adds, last add wins on duplicates, sorted output —
+    on streams with duplicate base edges, duplicate adds, deletes of
+    absent edges and delete-then-re-add lanes."""
+    rng = np.random.default_rng(seed)
+    n = int(rng.integers(2, 40))
+    m = int(rng.integers(0, 4 * n))
+    edges = rng.integers(0, n, size=(m, 2)).astype(np.int64)
+    w = rng.integers(1, 50, size=m).astype(np.int32)
+    adds = np.concatenate([
+        np.stack([rng.integers(0, n, 3 * n), rng.integers(0, n, 3 * n),
+                  rng.integers(1, 50, 3 * n)], axis=1),
+        np.concatenate([edges[: m // 3], w[: m // 3, None]], axis=1),
+    ]).astype(np.int32)
+    dels = np.concatenate([edges[::2], adds[::3, :2],
+                           rng.integers(0, n, size=(n, 2))]).astype(np.int32)
+    if seed == 0:                      # nothing at all
+        edges, w = edges[:0], w[:0]
+        adds, dels = adds[:0], dels[:0]
+    got_e, got_w = oracles.edges_after_updates(n, edges, w, adds, dels)
+    want_e, want_w = _edges_after_updates_dict(edges, w, adds, dels)
+    assert got_e.dtype == np.int64 and got_w.dtype == np.int32
+    np.testing.assert_array_equal(got_e.reshape(-1, 2), want_e)
+    np.testing.assert_array_equal(got_w, want_w)

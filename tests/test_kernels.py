@@ -1,8 +1,5 @@
 """Per-kernel interpret-mode validation against pure-jnp oracles,
 with shape/dtype sweeps and hypothesis randomization (brief §(c))."""
-import dataclasses
-import functools
-
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -10,13 +7,13 @@ import pytest
 from _hypothesis_compat import given, settings, st
 
 from repro.graph import build_csr, from_csr
-from repro.graph import diffcsr
 from repro.graph.csr import INF_W, INT
 from repro.kernels.ell import pack_ell, Ell
 from repro.kernels import csr_relax as K
 from repro.kernels import pallas_repair as FK
 from repro.kernels import ref as R
 from repro.kernels import ops as kops
+from repro.kernels.platform import interpret_mode
 from repro.kernels.flash_attention import flash_attention
 
 
@@ -87,7 +84,8 @@ def test_vertex_ops_match_segment_reduction():
 
 @pytest.mark.parametrize("n,e,k,block", [(64, 256, 8, 128),
                                          (200, 1000, 4, 128),
-                                         (300, 2000, 8, 256)])
+                                         (300, 2000, 8, 256),
+                                         (800, 5000, 8, 512)])
 def test_fused_relax_matches_chained(n, e, k, block):
     """One fused launch == rowmin → hit → rowargmin chain, bit-exact."""
     rng = np.random.default_rng(n + e)
@@ -103,26 +101,56 @@ def test_fused_relax_matches_chained(n, e, k, block):
     assert np.array_equal(np.asarray(hit), np.asarray(want_min) < INF_W)
 
 
-def test_fused_relax_frontier_compaction_invariants():
+@pytest.mark.parametrize("n,e,block", [(64, 256, 128), (800, 5000, 512)])
+def test_fused_relax_frontier_compaction_invariants(n, e, block):
     """The in-kernel compaction packs frontier row ids to each tile's
-    prefix (padded with sentinel R), and per-tile counts match."""
+    prefix (padded with sentinel R), in row order."""
     rng = np.random.default_rng(5)
-    n, block = 64, 128
-    _, ell = _random_ell(rng, n, 256, 8)
+    _, ell = _random_ell(rng, n, e, 8)
+    assert ell.R % block == 0
     vals = jnp.concatenate([
         jnp.asarray(rng.integers(0, 1000, n).astype(np.int32)),
         jnp.full((1,), INF_W, jnp.int32)])
-    rmin, _, rows, cnts = FK.fused_relax_rows(ell.ell_src, ell.ell_w, vals,
-                                              block=block)
+    rmin, _, rows = FK.fused_relax_rows(ell.ell_src, ell.ell_w, vals,
+                                        block=block)
     R_ = ell.R
-    hit_rows = np.nonzero(np.asarray(rmin) < INF_W)[0]
+    hit = np.asarray(rmin) < INF_W
     got_rows, got = np.asarray(rows), []
     for t in range(R_ // block):
-        c = int(np.asarray(cnts)[t])
+        c = int(hit[t * block:(t + 1) * block].sum())
         seg = got_rows[t * block:(t + 1) * block]
         assert (seg[:c] < R_).all() and (seg[c:] == R_).all(), t
         got.extend(seg[:c].tolist())
-    assert sorted(got) == hit_rows.tolist()
+    assert got == np.nonzero(hit)[0].tolist()
+
+
+@pytest.mark.parametrize("block", [128, 256, 512])
+def test_fused_spmv_frontier_compaction_invariants(block):
+    """The SpMV launch packs each tile's materialized rows (row2dst < n)
+    to the tile's prefix in row order, sentinel R after them."""
+    rng = np.random.default_rng(11)
+    n = 800
+    _, ell = _random_ell(rng, n, 5000, 8)
+    assert ell.R % block == 0
+    vals = jnp.concatenate([jnp.asarray(rng.random(n).astype(np.float32)),
+                            jnp.zeros((1,), jnp.float32)])
+    _, rows = FK.fused_spmv_rows(ell.ell_src, ell.row2dst, vals, block=block)
+    mat = np.asarray(ell.row2dst) < n
+    got_rows, got = np.asarray(rows), []
+    for t in range(ell.R // block):
+        c = int(mat[t * block:(t + 1) * block].sum())
+        seg = got_rows[t * block:(t + 1) * block]
+        assert (seg[c:] == ell.R).all(), t
+        got.extend(seg[:c].tolist())
+    assert got == np.nonzero(mat)[0].tolist()
+
+
+def test_interpret_mode_follows_platform():
+    """Kernels interpret off the TPU and compile on it; an explicit
+    bool (the compile checks' False) overrides the platform."""
+    assert interpret_mode() == (jax.default_backend() != "tpu")
+    assert interpret_mode(False) is False
+    assert interpret_mode(True) is True
 
 
 def test_fused_spmv_matches_chained():
@@ -140,43 +168,6 @@ def test_fused_spmv_matches_chained():
     assert np.array_equal(np.asarray(hit), np.asarray(want_hit))
 
 
-def _assert_graphs_equal(g1, g2):
-    for f in dataclasses.fields(g1):
-        a, b = getattr(g1, f.name), getattr(g2, f.name)
-        if f.name == "n":
-            assert a == b
-        else:
-            assert np.array_equal(np.asarray(a), np.asarray(b)), f.name
-
-
-@pytest.mark.parametrize("seed", [0, 1, 2, 3])
-def test_merge_kernel_matches_jnp_update(seed):
-    """update_csr_add with the merge-path kernel plugged in is bit-exact
-    against the scatter path — dedupe, revivals and overflow included."""
-    rng = np.random.default_rng(seed)
-    n = 40
-    e = rng.integers(0, n, size=(120, 2))
-    e = e[e[:, 0] != e[:, 1]]
-    csr = build_csr(n, e, rng.integers(1, 50, len(e)).astype(np.int32))
-    d = int(rng.integers(3, 24))
-    g = from_csr(csr, diff_capacity=d)
-    merge_impl = functools.partial(FK.merge_pool_sorted, block=128,
-                                   interpret=True)
-    for step in range(4):
-        B = int(rng.integers(2, 12))
-        qs = jnp.asarray(rng.integers(0, n, B).astype(np.int32))
-        qd = jnp.asarray(rng.integers(0, n, B).astype(np.int32))
-        qw = jnp.asarray(rng.integers(1, 50, B).astype(np.int32))
-        mask = jnp.asarray(rng.random(B) < 0.9)
-        g1 = diffcsr.update_csr_add(g, qs, qd, qw, mask)
-        g2 = diffcsr.update_csr_add(g, qs, qd, qw, mask,
-                                    pool_merge=merge_impl)
-        _assert_graphs_equal(g1, g2)
-        g = g1
-        if step == 1:  # interleave tombstones so revivals get exercised
-            g = diffcsr.update_csr_del(g, qs[: B // 2], qd[: B // 2])
-
-
 def test_repair_config_cached_per_shape():
     FK.clear_tune_cache()
     c1 = FK.repair_config(64, 300, 8)
@@ -187,7 +178,6 @@ def test_repair_config_cached_per_shape():
     FK.clear_tune_cache()
     cm = FK.repair_config(32, 64, 8, measure=True)     # timed candidates
     assert cm.row_block in (128, 256, 512)
-    assert cm.merge_block in (128, 256)
     FK.clear_tune_cache()
 
 

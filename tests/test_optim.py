@@ -53,9 +53,8 @@ def test_compressed_psum_single_axis():
                                      group_size=len(devs))
         return y[None]
 
-    from jax.experimental.shard_map import shard_map
-    out = jax.jit(shard_map(f, mesh=mesh, in_specs=(P("pod"),),
-                            out_specs=P("pod")))(jnp.asarray(x))
+    out = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=(P("pod"),),
+                                out_specs=P("pod")))(jnp.asarray(x))
     exact = x.sum(axis=0)
     got = np.asarray(out)[0]
     err = np.abs(got - exact)
