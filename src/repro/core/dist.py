@@ -25,15 +25,15 @@ Faithful mapping of the paper's §3.6/§5.2 distributed design:
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Callable, Dict
 
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.extend.core import ClosedJaxpr, jaxpr_as_fun
 from jax.sharding import Mesh, PartitionSpec as P, NamedSharding
 
-from repro.core.ir import EdgeSweep, Reduce, trace_read_set
+from repro.core.ir import EdgeSweep, Reduce
 from repro.core.engine import Engine, Collectives, Props, WedgeCtx, \
     edge_lane_flags, _STREAM_CACHE_LOCK
 from repro.graph.csr import CSR, INT, build_csr
@@ -144,16 +144,9 @@ class _DistStreamView(Engine):
         # vertex properties inside the stream scan are (block,) shards
         return jnp.full((self._o.block,), value, dtype=dtype)
 
-    # -- aggregate ops -----------------------------------------------------
+    # -- aggregate ops: the outer engine's in-shard bodies ----------------
     def vertex_map(self, g, fn, props: Props) -> Props:
-        ax = self._o.axis
-        blk = self._o.block
-        full = {k: jax.lax.all_gather(v, ax, tiled=True)
-                for k, v in props.items()}
-        out = fn(full)
-        i = jax.lax.axis_index(ax)
-        return {k: jax.lax.dynamic_slice(v, (i * blk,), (blk,))
-                for k, v in out.items()}
+        return self._o._vertex_map_local(fn, props)
 
     def sweep(self, g, sw: EdgeSweep, props: Props) -> Props:
         read_set = frozenset(sw.read_set(props))
@@ -167,41 +160,16 @@ class _DistStreamView(Engine):
 
     def fixed_point(self, g, sw: EdgeSweep, props: Props, cond_fn,
                     max_iter: int) -> Props:
-        read_set = frozenset(sw.read_set(props))
-        col = DistCollectives(self._o.axis)
-
-        def cond(state):
-            it, p = state
-            return (it < max_iter) & cond_fn(p, it, col)
-
-        def body(state):
-            it, p = state
-            return it + 1, self._o._sweep_local(g, sw, p, read_set)
-
-        _, out = jax.lax.while_loop(cond, body, (jnp.zeros((), INT), props))
-        return out
+        return self._o._fixed_point_local(g, sw, props, cond_fn, max_iter)
 
     def out_degrees(self, g) -> jax.Array:
-        esrc, _, _, ealive = g.edge_arrays()
-        dense = jax.ops.segment_sum(ealive.astype(INT), esrc,
-                                    num_segments=self.n_pad)
-        dense = jax.lax.psum(dense, self._o.axis)
-        i = jax.lax.axis_index(self._o.axis)
-        return jax.lax.dynamic_slice(dense, (i * self._o.block,),
-                                     (self._o.block,))
+        return self._o._out_degrees_local(g)
 
-    # -- dynamic updates (ownership-masked, straight onto the local graph) --
     def update_del(self, g, batch: UpdateBatch):
-        i = jax.lax.axis_index(self._o.axis)
-        own = (batch.del_src // self._o.block) == i
-        return diffcsr.update_csr_del(g, batch.del_src, batch.del_dst,
-                                      batch.del_mask & own)
+        return self._o._update_del_local(g, batch)
 
     def update_add(self, g, batch: UpdateBatch):
-        i = jax.lax.axis_index(self._o.axis)
-        own = (batch.add_src // self._o.block) == i
-        return diffcsr.update_csr_add(g, batch.add_src, batch.add_dst,
-                                      batch.add_w, batch.add_mask & own)
+        return self._o._update_add_local(g, batch)
 
     def batch_edge_flags(self, g, qs, qd, mask) -> jax.Array:
         return edge_lane_flags(g, qs, qd, mask)
@@ -239,6 +207,35 @@ class DistEngine(Engine):
     def _shmap(self, fn, in_specs, out_specs):
         return jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
                              out_specs=out_specs, check_vma=False)
+
+    def _spmd(self, fn, in_specs, out_specs, *args):
+        """``jit(shard_map(fn))(*args)``, compiled once per program.
+
+        ``fn`` is a closure built afresh on each call, over a sweep's or
+        a DSL statement's callables and over the call's own arrays and
+        host scalars, so neither its identity nor its code objects say
+        which program it is.  Tracing it to a jaxpr does, and is cheap
+        next to a compile: the memo in ``_stream_cache`` is keyed on the
+        printed jaxpr, in which host scalars are literals, plus the
+        handle's shape key so that ``grow`` evicts what it strands.  The
+        arrays the closure captures are the jaxpr's constants and enter
+        the compiled function as arguments, so a hit never sees another
+        call's values."""
+        traced, out_shape = jax.make_jaxpr(
+            self._shmap(fn, in_specs, out_specs), return_shape=True)(*args)
+        shape_key = (self._handle_shape_key(args[0])
+                     if isinstance(args[0], DistGraph) else None)
+        key = ("spmd", shape_key, str(traced.jaxpr))
+        with _STREAM_CACHE_LOCK:
+            run = self._stream_cache.get(key)
+            if run is None:
+                jaxpr = traced.jaxpr
+                run = jax.jit(lambda consts, leaves: jaxpr_as_fun(
+                    ClosedJaxpr(jaxpr, consts))(*leaves))
+                self._stream_cache[key] = run
+        out = run(traced.consts, jax.tree_util.tree_leaves(args))
+        return jax.tree_util.tree_unflatten(
+            jax.tree_util.tree_structure(out_shape), out)
 
     def _gspec(self):
         """Sharding spec for a stacked DistGraph pytree."""
@@ -372,11 +369,19 @@ class DistEngine(Engine):
         cap = dg.d_src.shape[1]
         return self.merge(dg, diff_capacity=max(int(cap * factor), cap + 16))
 
+    # -- in-shard (un)stacking of the handle; ShardedEngine adds its halo
+    # tables and overrides these three --------------------------------------
+    _unstack = staticmethod(_local)
+    _stack = staticmethod(_restack)
+
+    @staticmethod
+    def _compact_local(g: DynGraph) -> DynGraph:
+        return diffcsr.compact(g)
+
     def compact_handle(self, dg: DistGraph) -> DistGraph:
-        def fn(dgl):
-            return _restack(diffcsr.compact(_local(dgl)))
-        return self._shmap(fn, in_specs=(self._gspec(),),
-                           out_specs=self._gspec())(dg)
+        return self._spmd(
+            lambda d: self._stack(self._compact_local(self._unstack(d))),
+            (self._gspec(),), self._gspec(), dg)
 
     def _diff_capacity(self, dg: DistGraph) -> int:
         return int(dg.d_src.shape[1])
@@ -429,18 +434,18 @@ class DistEngine(Engine):
         return self._run_stream_fused(dg, stream, batch_size, step_fn,
                                       carry, segment_size, compact_frac)
 
+    def _out_degrees_local(self, g: DynGraph) -> jax.Array:
+        esrc, _, _, ealive = g.edge_arrays()
+        dense = jax.ops.segment_sum(ealive.astype(INT), esrc,
+                                    num_segments=self.n_pad)
+        dense = jax.lax.psum(dense, self.axis)
+        i = jax.lax.axis_index(self.axis)
+        return jax.lax.dynamic_slice(dense, (i * self.block,),
+                                     (self.block,))
+
     def out_degrees(self, dg: DistGraph) -> jax.Array:
-        def fn(dgl):
-            g = _local(dgl)
-            esrc, _, _, ealive = g.edge_arrays()
-            dense = jax.ops.segment_sum(ealive.astype(INT), esrc,
-                                        num_segments=self.n_pad)
-            dense = jax.lax.psum(dense, self.axis)
-            i = jax.lax.axis_index(self.axis)
-            return jax.lax.dynamic_slice(dense, (i * self.block,),
-                                         (self.block,))
-        return self._shmap(fn, in_specs=(self._gspec(),),
-                           out_specs=self._pspec())(dg)
+        return self._spmd(lambda d: self._out_degrees_local(_local(d)),
+                          (self._gspec(),), self._pspec(), dg)
 
     # -- core sweep (inside-shard_map body shared with fixed_point) ---------
     def _sweep_local(self, g: DynGraph, sw: EdgeSweep, lp: Props,
@@ -486,51 +491,48 @@ class DistEngine(Engine):
         hit = {k: blk(v) for k, v in hit.items()}
         return sw.post_fn(lp, reduced, hit)
 
-    def sweep(self, dg: DistGraph, sw: EdgeSweep, props: Props) -> Props:
-        read_set = frozenset(sw.read_set(props))
-
-        def fn(dgl, p):
-            return self._sweep_local(_local(dgl), sw, p, read_set)
-
-        return self._shmap(
-            fn, in_specs=(self._gspec(), self._pspec()),
-            out_specs=self._pspec())(dg, props)
-
-    def fixed_point(self, dg: DistGraph, sw: EdgeSweep, props: Props,
-                    cond_fn: Callable, max_iter: int) -> Props:
+    def _fixed_point_local(self, g, sw: EdgeSweep, props: Props,
+                           cond_fn: Callable, max_iter: int) -> Props:
         read_set = frozenset(sw.read_set(props))
         col = DistCollectives(self.axis)
 
-        def fn(dgl, p0):
-            g = _local(dgl)
+        def cond(state):
+            it, p = state
+            return (it < max_iter) & cond_fn(p, it, col)
 
-            def cond(state):
-                it, p = state
-                return (it < max_iter) & cond_fn(p, it, col)
+        def body(state):
+            it, p = state
+            return it + 1, self._sweep_local(g, sw, p, read_set)
 
-            def body(state):
-                it, p = state
-                return it + 1, self._sweep_local(g, sw, p, read_set)
+        _, out = jax.lax.while_loop(cond, body, (jnp.zeros((), INT), props))
+        return out
 
-            _, out = jax.lax.while_loop(cond, body,
-                                        (jnp.zeros((), INT), p0))
-            return out
+    def _vertex_map_local(self, fn: Callable, props: Props) -> Props:
+        full = {k: jax.lax.all_gather(v, self.axis, tiled=True)
+                for k, v in props.items()}
+        out = fn(full)
+        i = jax.lax.axis_index(self.axis)
+        return {k: jax.lax.dynamic_slice(v, (i * self.block,),
+                                         (self.block,))
+                for k, v in out.items()}
 
-        return self._shmap(
-            fn, in_specs=(self._gspec(), self._pspec()),
-            out_specs=self._pspec())(dg, props)
+    def sweep(self, dg: DistGraph, sw: EdgeSweep, props: Props) -> Props:
+        read_set = frozenset(sw.read_set(props))
+        return self._spmd(
+            lambda d, p: self._sweep_local(self._unstack(d), sw, p,
+                                           read_set),
+            (self._gspec(), self._pspec()), self._pspec(), dg, props)
+
+    def fixed_point(self, dg: DistGraph, sw: EdgeSweep, props: Props,
+                    cond_fn: Callable, max_iter: int) -> Props:
+        return self._spmd(
+            lambda d, p: self._fixed_point_local(self._unstack(d), sw, p,
+                                                 cond_fn, max_iter),
+            (self._gspec(), self._pspec()), self._pspec(), dg, props)
 
     def vertex_map(self, dg: DistGraph, fn: Callable, props: Props) -> Props:
-        def body(p):
-            full = {k: jax.lax.all_gather(v, self.axis, tiled=True)
-                    for k, v in p.items()}
-            out = fn(full)
-            i = jax.lax.axis_index(self.axis)
-            return {k: jax.lax.dynamic_slice(v, (i * self.block,),
-                                             (self.block,))
-                    for k, v in out.items()}
-        return self._shmap(body, in_specs=(self._pspec(),),
-                           out_specs=self._pspec())(props)
+        return self._spmd(lambda p: self._vertex_map_local(fn, p),
+                          (self._pspec(),), self._pspec(), props)
 
     # -- wedges --------------------------------------------------------------
     def _count_wedges_local(self, g: DynGraph, flags: Dict[str, jax.Array],
@@ -615,54 +617,42 @@ class DistEngine(Engine):
                 if doffs.size else 0
 
         def fn(dgl, flags):
-            g = _local(dgl)
             flags = {k: v[0] for k, v in flags.items()}
-            return self._count_wedges_local(g, flags, pair_fn, out_example,
-                                            max_main, max_diff)
+            return self._count_wedges_local(_local(dgl), flags, pair_fn,
+                                            out_example, max_main, max_diff)
 
-        flag_specs = {k: P(self.axis) for k in lane_flags}
-        return self._shmap(
-            fn, in_specs=(self._gspec(), flag_specs),
-            out_specs=jax.tree_util.tree_map(lambda _: P(), out_example)
-        )(dg, lane_flags)
+        return self._spmd(
+            fn, (self._gspec(), {k: P(self.axis) for k in lane_flags}),
+            jax.tree_util.tree_map(lambda _: P(), out_example),
+            dg, lane_flags)
 
-    # -- updates --------------------------------------------------------------
+    # -- updates (ownership-masked, straight onto the local graph) ------------
+    def _update_del_local(self, g: DynGraph, b: UpdateBatch) -> DynGraph:
+        own = (b.del_src // self.block) == jax.lax.axis_index(self.axis)
+        return diffcsr.update_csr_del(g, b.del_src, b.del_dst,
+                                      b.del_mask & own)
+
+    def _update_add_local(self, g: DynGraph, b: UpdateBatch) -> DynGraph:
+        own = (b.add_src // self.block) == jax.lax.axis_index(self.axis)
+        return diffcsr.update_csr_add(g, b.add_src, b.add_dst, b.add_w,
+                                      b.add_mask & own)
+
     def update_del(self, dg: DistGraph, batch: UpdateBatch) -> DistGraph:
-        blk = self.block
-
-        def fn(dgl, b):
-            g = _local(dgl)
-            i = jax.lax.axis_index(self.axis)
-            own = (b.del_src // blk) == i
-            g2 = diffcsr.update_csr_del(g, b.del_src, b.del_dst,
-                                        b.del_mask & own)
-            return _restack(g2)
-
-        return self._shmap(
-            fn, in_specs=(self._gspec(), P()), out_specs=self._gspec()
-        )(dg, batch)
+        return self._spmd(
+            lambda d, b: self._stack(self._update_del_local(
+                self._unstack(d), b)),
+            (self._gspec(), P()), self._gspec(), dg, batch)
 
     def update_add(self, dg: DistGraph, batch: UpdateBatch) -> DistGraph:
-        blk = self.block
-
-        def fn(dgl, b):
-            g = _local(dgl)
-            i = jax.lax.axis_index(self.axis)
-            own = (b.add_src // blk) == i
-            g2 = diffcsr.update_csr_add(g, b.add_src, b.add_dst, b.add_w,
-                                        b.add_mask & own)
-            return _restack(g2)
-
-        return self._shmap(
-            fn, in_specs=(self._gspec(), P()), out_specs=self._gspec()
-        )(dg, batch)
+        return self._spmd(
+            lambda d, b: self._stack(self._update_add_local(
+                self._unstack(d), b)),
+            (self._gspec(), P()), self._gspec(), dg, batch)
 
     def batch_edge_flags(self, dg: DistGraph, qs, qd, mask) -> jax.Array:
-        def fn(dgl):
-            g = _local(dgl)
-            return edge_lane_flags(g, qs, qd, mask)[None]
-        return self._shmap(fn, in_specs=(self._gspec(),),
-                           out_specs=P(self.axis))(dg)
+        return self._spmd(
+            lambda d: edge_lane_flags(_local(d), qs, qd, mask)[None],
+            (self._gspec(),), P(self.axis), dg)
 
 
 class _DView:

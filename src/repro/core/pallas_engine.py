@@ -18,7 +18,7 @@ Two kernel regimes, selected by the ``fused`` flag:
     the benchmark baseline for BENCH_pallas.json.
 
 Both regimes are bit-exact against each other and the jnp lowering
-(tests/test_kernels.py, tests/test_conformance.py).  ΔG merges into the
+(tests/test_kernels.py, tests/test_conformance_pallas.py).  ΔG merges into the
 diff pool run on the jnp lowering (``diffcsr.update_csr_add``) in both.
 Kernels compile on a TPU and run in the Pallas interpreter elsewhere
 (``kernels.platform.interpret_mode``).

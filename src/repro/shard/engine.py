@@ -32,7 +32,7 @@ partitions the data structure (ROADMAP item 1):
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable, Dict
+from typing import Dict
 
 import numpy as np
 import jax
@@ -40,11 +40,8 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P, NamedSharding
 
 from repro.core.ir import EdgeSweep
-from repro.core.engine import Engine, Props, edge_lane_flags, \
-    _STREAM_CACHE_LOCK
-from repro.core.dist import DistEngine, DistGraph, DistCollectives, \
-    _DistStreamView, _DView
-from repro.core import dist as _dist
+from repro.core.engine import Props, edge_lane_flags, _STREAM_CACHE_LOCK
+from repro.core.dist import DistEngine, DistGraph, _DistStreamView, _DView
 from repro.graph.csr import CSR, INT, build_csr
 from repro.graph import diffcsr
 from repro.graph.diffcsr import DynGraph, BOOL
@@ -127,12 +124,6 @@ class _ShardStreamView(_DistStreamView):
     def __init__(self, outer: "ShardedEngine", bounds=None):
         super().__init__(outer)
         self._bounds = bounds
-
-    def update_del(self, ls: LocalShard, batch: UpdateBatch) -> LocalShard:
-        return self._o._update_del_local(ls, batch)
-
-    def update_add(self, ls: LocalShard, batch: UpdateBatch) -> LocalShard:
-        return self._o._update_add_local(ls, batch)
 
     def batch_edge_flags(self, ls: LocalShard, qs, qd, mask) -> jax.Array:
         return edge_lane_flags(ls.g, qs, qd, mask)
@@ -288,12 +279,12 @@ class ShardedEngine(DistEngine):
         new_cap = cap if halo_only else max(int(cap * factor), cap + 16)
         return self.merge(sg, diff_capacity=new_cap)
 
-    def compact_handle(self, sg: ShardGraph) -> ShardGraph:
-        def fn(sgl):
-            ls = _slocal(sgl)
-            return _srestack(dataclasses.replace(ls, g=diffcsr.compact(ls.g)))
-        return self._shmap(fn, in_specs=(self._gspec(),),
-                           out_specs=self._gspec())(sg)
+    _unstack = staticmethod(_slocal)
+    _stack = staticmethod(_srestack)
+
+    @staticmethod
+    def _compact_local(ls: LocalShard) -> LocalShard:
+        return dataclasses.replace(ls, g=diffcsr.compact(ls.g))
 
     def _handle_shape_key(self, sg: ShardGraph) -> tuple:
         return (int(sg.src.shape[1]), int(sg.d_src.shape[1]),
@@ -509,38 +500,6 @@ class ShardedEngine(DistEngine):
                 hit[target] = hit[red.of]
         return sw.post_fn(lp, reduced, hit)
 
-    def sweep(self, sg: ShardGraph, sw: EdgeSweep, props: Props) -> Props:
-        read_set = frozenset(sw.read_set(props))
-
-        def fn(sgl, p):
-            return self._sweep_local(_slocal(sgl), sw, p, read_set)
-
-        return self._shmap(fn, in_specs=(self._gspec(), self._pspec()),
-                           out_specs=self._pspec())(sg, props)
-
-    def fixed_point(self, sg: ShardGraph, sw: EdgeSweep, props: Props,
-                    cond_fn: Callable, max_iter: int) -> Props:
-        read_set = frozenset(sw.read_set(props))
-        col = DistCollectives(self.axis)
-
-        def fn(sgl, p0):
-            ls = _slocal(sgl)
-
-            def cond(state):
-                it, p = state
-                return (it < max_iter) & cond_fn(p, it, col)
-
-            def body(state):
-                it, p = state
-                return it + 1, self._sweep_local(ls, sw, p, read_set)
-
-            _, out = jax.lax.while_loop(cond, body,
-                                        (jnp.zeros((), INT), p0))
-            return out
-
-        return self._shmap(fn, in_specs=(self._gspec(), self._pspec()),
-                           out_specs=self._pspec())(sg, props)
-
     # -- dynamic updates (row-ownership-masked, miss-recording) ------------
     def _row_owner(self, ls: LocalShard, v):
         return jnp.searchsorted(ls.row_starts, jnp.asarray(v, INT),
@@ -585,15 +544,3 @@ class ShardedEngine(DistEngine):
         ids = jnp.concatenate([jnp.asarray(b.add_src, INT),
                                jnp.asarray(b.add_dst, INT)])
         return self._note_misses(ls, ids, jnp.concatenate([m, m]))
-
-    def update_del(self, sg: ShardGraph, batch: UpdateBatch) -> ShardGraph:
-        def fn(sgl, b):
-            return _srestack(self._update_del_local(_slocal(sgl), b))
-        return self._shmap(fn, in_specs=(self._gspec(), P()),
-                           out_specs=self._gspec())(sg, batch)
-
-    def update_add(self, sg: ShardGraph, batch: UpdateBatch) -> ShardGraph:
-        def fn(sgl, b):
-            return _srestack(self._update_add_local(_slocal(sgl), b))
-        return self._shmap(fn, in_specs=(self._gspec(), P()),
-                           out_specs=self._gspec())(sg, batch)

@@ -20,7 +20,11 @@ algorithm, compile its ``.sp`` program, add an ``assert_<algo>`` runner
 against its oracle, and register scenarios below (see ROADMAP.md).
 Backends are addressed by registry name ('jnp' | 'dist' | 'pallas' |
 'frontier'), so a newly registered engine joins the matrix by adding
-its name to the lists in test_conformance.py.
+its name to the lists in :func:`matrix`.
+
+The matrix's test functions are built by :func:`matrix` and collected
+from one ``test_conformance_<group>.py`` file per backend group, so
+``pytest-xdist --dist loadfile`` spreads the groups over workers.
 """
 from __future__ import annotations
 
@@ -29,6 +33,7 @@ import functools
 import warnings
 
 import numpy as np
+import pytest
 
 import repro.api as api
 from repro.core.registry import make_engine
@@ -561,3 +566,197 @@ def assert_tc(backend: str, sc: Scenario):
     count = gsess.call(hand_tc.dyn_tc, sc.stream, sc.batch_size)
     assert int(count) == ref, \
         f"[{sc.name}] hand-staged dyn_tc {int(count)} != oracle {ref}"
+
+
+# ---------------------------------------------------------------------------
+# The matrix: program × backend × scenario cells as pytest test functions
+# ---------------------------------------------------------------------------
+
+BACKENDS = ["jnp", "dist", "dist_sharded", "pallas"]
+
+SSSP_SCENARIOS = ["batch1", "batch8", "batch64", "empty", "self_loops",
+                  "dup_in_batch", "del_then_readd"]
+PR_SCENARIOS = ["batch1", "batch8", "batch64", "del_then_readd"]
+TC_SCENARIOS = ["sym_batch2", "sym_batch16", "sym_empty", "sym_del_readd"]
+
+# the dist cell that stays in the fast lane: a single whole-Δ batch
+DIST_FAST = {"batch64"}
+
+# backends whose cells mostly run in the slow lane (one fast
+# representative each), which keeps the fast lane short: dist and
+# dist_sharded are the widest columns, pallas_chained is the pre-fusion
+# baseline kept honest by one cell per program.
+_MOSTLY_SLOW = {"dist", "dist_sharded", "pallas_chained"}
+
+
+def _cells(scenarios, backends, fast=DIST_FAST, prefix=""):
+    out = []
+    for s in scenarios:
+        for b in backends:
+            marks = ()
+            if b in _MOSTLY_SLOW and s not in fast:
+                marks = (pytest.mark.slow,)
+            out.append(pytest.param(s, b, marks=marks,
+                                    id=f"{prefix}{s}-{b}"))
+    return out
+
+
+# TC's wedge enumeration on the dist backend is the paper's admitted MPI
+# bottleneck; the two fast engines cover the kernel surface here while
+# test_backends.py keeps one dist TC case.  dist_sharded joins the
+# column (halo'd wedge bounds make sharded TC work) with its own fast
+# representative — DIST_FAST names no symmetric scenario.
+TC_FAST = {"sym_batch2"}
+
+# Streaming-executor cells: the same scenarios driven through
+# GraphSession.run_stream (one fused lax.scan per segment) must stay
+# oracle-exact on every backend.  Scenario-representative subset per
+# program keeps the fast lane fast; dist cells follow the DIST_FAST rule.
+STREAM_SSSP = ["batch1", "batch8", "empty", "dup_in_batch", "del_then_readd"]
+STREAM_PR = ["batch1", "batch8", "del_then_readd"]
+STREAM_TC = ["sym_batch2", "sym_empty", "sym_del_readd"]
+# the dist stream cell that stays in the fast lane
+DIST_STREAM_FAST = {"batch8"}
+
+# Adversarial-stream cells (admission guard, DESIGN.md §6): a poison
+# batch prepended to the scenario stream must leave the final state
+# oracle-exact against the CLEAN stream after the guard disposes of it
+# (clamp → masked no-op, quarantine → dead-letter).  Both the DSL
+# one-shot path and the fused streaming executor get cells on every
+# registered backend.
+POISON_SCENARIOS = ["batch8", "batch64"]
+POISON_POLICIES = ["clamp", "quarantine"]
+# the admission guard sits in front of the engine, so its cells need
+# one distributed representative, not two: dist covers the shard_map
+# column and dist_sharded stays out of the poison grid
+POISON_BACKENDS = [b for b in BACKENDS if b != "dist_sharded"]
+
+
+def _poison_cells(scenarios, backends, fast=DIST_FAST):
+    out = []
+    for s in scenarios:
+        for b in backends:
+            for p in POISON_POLICIES:
+                marks = ()
+                if b in _MOSTLY_SLOW and s not in fast:
+                    marks = (pytest.mark.slow,)
+                out.append(pytest.param(s, b, p, marks=marks,
+                                        id=f"poison-{s}-{b}-{p}"))
+    return out
+
+
+# Durability cells: arm the Batch loop, apply half the stream, save,
+# restore from disk, apply the rest — bit-identical to the uninterrupted
+# armed run (see assert_sssp_save_restore).  Every registered backend
+# gets a cell; dist's restores onto a second engine, so it rides the
+# slow lane alongside pallas_chained per the _MOSTLY_SLOW convention.
+DURABLE_BACKENDS = ["jnp", "dist", "dist_sharded", "pallas",
+                    "pallas_chained", "frontier"]
+
+
+def matrix(backends) -> dict:
+    """The matrix's test functions, each parametrized over the cells
+    whose (saving) backend is in ``backends``; tests with no such cell
+    are left out.  A ``test_conformance_<group>.py`` file collects them
+    with ``globals().update(matrix({...}))``."""
+    tests = {}
+
+    def add(fn, argnames, cells, col=1):
+        mine = [c for c in cells if c.values[col] in backends]
+        if mine:
+            tests[fn.__name__] = pytest.mark.parametrize(argnames,
+                                                         mine)(fn)
+
+    def test_conformance_sssp(scenario, backend):
+        assert_sssp(backend, digraph_scenario(scenario))
+    add(test_conformance_sssp, ("scenario", "backend"),
+        _cells(SSSP_SCENARIOS, BACKENDS))
+
+    def test_conformance_pagerank(scenario, backend):
+        assert_pagerank(backend, digraph_scenario(scenario))
+    add(test_conformance_pagerank, ("scenario", "backend"),
+        _cells(PR_SCENARIOS, BACKENDS))
+
+    def test_conformance_tc(scenario, backend):
+        assert_tc(backend, sym_scenario(scenario))
+    add(test_conformance_tc, ("scenario", "backend"),
+        _cells(TC_SCENARIOS, ["jnp", "dist_sharded", "pallas"],
+               fast=TC_FAST))
+
+    def test_stream_conformance_sssp(scenario, backend):
+        assert_sssp_stream(backend, digraph_scenario(scenario))
+    add(test_stream_conformance_sssp, ("scenario", "backend"),
+        _cells(STREAM_SSSP, BACKENDS + ["pallas_chained", "frontier"],
+               fast=DIST_STREAM_FAST, prefix="stream-"))
+
+    def test_stream_conformance_pagerank(scenario, backend):
+        assert_pagerank_stream(backend, digraph_scenario(scenario))
+    add(test_stream_conformance_pagerank, ("scenario", "backend"),
+        _cells(STREAM_PR, BACKENDS + ["pallas_chained", "frontier"],
+               fast=DIST_STREAM_FAST, prefix="stream-"))
+
+    # dist refuses wedge enumeration inside the fused scan (no static
+    # bounds); dist_sharded provides them, so the sharded column is the
+    # FIRST distributed engine in the streaming-TC row.
+    def test_stream_conformance_tc(scenario, backend):
+        assert_tc_stream(backend, sym_scenario(scenario))
+    add(test_stream_conformance_tc, ("scenario", "backend"),
+        _cells(STREAM_TC, ["jnp", "dist_sharded", "pallas"], fast=TC_FAST,
+               prefix="stream-"))
+
+    def test_conformance_sssp_poison(scenario, backend, policy):
+        assert_sssp_poison(backend, digraph_scenario(scenario), policy)
+    add(test_conformance_sssp_poison, ("scenario", "backend", "policy"),
+        _poison_cells(POISON_SCENARIOS, POISON_BACKENDS))
+
+    def test_stream_conformance_sssp_poison(scenario, backend, policy):
+        assert_sssp_stream_poison(backend, digraph_scenario(scenario),
+                                  policy)
+    add(test_stream_conformance_sssp_poison,
+        ("scenario", "backend", "policy"),
+        _poison_cells(["batch8"],
+                      POISON_BACKENDS + ["pallas_chained", "frontier"],
+                      fast=DIST_STREAM_FAST))
+
+    def test_conformance_sssp_save_restore(scenario, backend, tmp_path):
+        assert_sssp_save_restore(backend, digraph_scenario(scenario),
+                                 tmp_path)
+    add(test_conformance_sssp_save_restore, ("scenario", "backend"),
+        _cells(["batch8"], DURABLE_BACKENDS, fast=set(), prefix="ckpt-"))
+
+    # elastic re-mesh: save on the full mesh, restore onto half of it (on
+    # a single-device host this degenerates to 1 -> 1, which still walks
+    # the pack/re-partition path; CI's dist-smoke job runs it at 8 -> 4)
+    if "dist_sharded" in backends:
+        @pytest.mark.slow
+        def test_conformance_sssp_save_restore_remesh(tmp_path):
+            import jax
+            m = max(1, len(jax.devices()) // 2)
+            assert_sssp_save_restore("dist_sharded",
+                                     digraph_scenario("batch8"), tmp_path,
+                                     restore_opts={"num_shards": m})
+        tests[test_conformance_sssp_save_restore_remesh.__name__] = \
+            test_conformance_sssp_save_restore_remesh
+
+    # float bit-exactness: raw-leaf restore preserves the diff-pool
+    # layout and ELL pack, so resumed PageRank is bit-identical, not
+    # just close
+    def test_conformance_pagerank_save_restore(scenario, backend,
+                                               tmp_path):
+        assert_pagerank_save_restore(backend, digraph_scenario(scenario),
+                                     tmp_path)
+    add(test_conformance_pagerank_save_restore, ("scenario", "backend"),
+        _cells(["batch8"], ["jnp", "pallas"], fast=set(), prefix="ckpt-"))
+
+    # cross-backend restore: the checkpoint converts through the
+    # canonical alive-edge list; SSSP's int-min fold makes the contract
+    # still bit-exact
+    def test_conformance_cross_backend_restore(save_backend,
+                                               restore_backend, tmp_path):
+        assert_sssp_save_restore(save_backend, digraph_scenario("batch8"),
+                                 tmp_path, restore_backend=restore_backend)
+    add(test_conformance_cross_backend_restore,
+        ("save_backend", "restore_backend"),
+        [pytest.param("jnp", "pallas", id="ckpt-jnp-to-pallas"),
+         pytest.param("pallas", "jnp", id="ckpt-pallas-to-jnp")], col=0)
+    return tests

@@ -1,5 +1,6 @@
-import sys
 import pathlib
+import signal
+import sys
 
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1] / "src"))
 
@@ -9,23 +10,32 @@ import pytest
 from repro.graph import build_csr
 from repro.algos import oracles
 
-# XLA's CPU backend segfaults mid-compile once enough jitted executables
-# accumulate in one long pytest process (observed deterministically around
-# the 59th fast-lane test on single-core hosts).  Every test builds its
-# own engines/graphs, so dropping the global jit caches between tests is
-# semantically free — do it every CLEAR_EVERY tests to bound the resident
-# compiled-code footprint without paying a full recompile per test.
-_CLEAR_EVERY = 24
-_test_count = {"n": 0}
+# Every test runs under a time limit of its own: SIGALRM raises
+# TimeoutError in the test once it has run TIME_LIMIT_S seconds (xdist
+# workers run tests on their main thread, where the signal lands).  The
+# slowest test takes about two minutes on a CPU worker beside five
+# others, so the limit is three times that; a test that needs another
+# limit carries ``@pytest.mark.timeout(seconds)``.  A test that starts a
+# subprocess derives that child's own timeout from the ``time_limit``
+# fixture.
+TIME_LIMIT_S = 360
 
 
 @pytest.fixture(autouse=True)
-def _bounded_jit_cache():
-    yield
-    _test_count["n"] += 1
-    if _test_count["n"] % _CLEAR_EVERY == 0:
-        import jax
-        jax.clear_caches()
+def time_limit(request):
+    marker = request.node.get_closest_marker("timeout")
+    limit = marker.args[0] if marker else TIME_LIMIT_S
+
+    def expire(signum, frame):
+        raise TimeoutError(f"test ran past its {limit} s time limit")
+
+    previous = signal.signal(signal.SIGALRM, expire)
+    signal.setitimer(signal.ITIMER_REAL, limit)
+    try:
+        yield limit
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, previous)
 
 
 def random_digraph(n=60, deg=4, seed=3, max_w=100):

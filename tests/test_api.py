@@ -5,7 +5,7 @@ The contract under test (ISSUE 5 acceptance criteria):
 
   * ``CompiledProgram.bind(...).run(...)`` matches the deprecated
     ``Program.run`` shim bit-exactly (spot-checked here; the full
-    program × backend × scenario sweep lives in test_conformance.py);
+    program × backend × scenario sweep lives in test_conformance_*.py);
   * a session that stays bound across N update batches produces
     identical results to a one-shot run over the same N batches, while
     calling ``engine.prepare`` exactly once;

@@ -21,8 +21,8 @@ from repro.core.pallas_engine import PallasEngine
 from repro.core.frontier_engine import FrontierEngine
 from repro.algos import sssp, pagerank, triangles, oracles
 
-# shard_map tracing makes the dist cells ~2min each on CPU; they run in
-# the full lane, while conformance keeps a fast dist cell per program.
+# the dist cells run in the full lane, while conformance keeps a fast
+# dist cell per program.
 ENGINES = [JnpEngine,
            pytest.param(DistEngine, marks=pytest.mark.slow),
            PallasEngine, FrontierEngine]
@@ -104,7 +104,7 @@ _SUBPROC = textwrap.dedent("""
 
 
 @pytest.mark.slow
-def test_dist_8_virtual_devices(tmp_path):
+def test_dist_8_virtual_devices(tmp_path, time_limit):
     import pathlib
     here = pathlib.Path(__file__).resolve()
     src = str(here.parents[1] / "src")
@@ -112,6 +112,6 @@ def test_dist_8_virtual_devices(tmp_path):
     script.write_text(_SUBPROC)
     r = subprocess.run(
         [sys.executable, str(script), src, str(here.parent)],
-        capture_output=True, text=True, timeout=1200,
+        capture_output=True, text=True, timeout=time_limit - 10,
         env={**os.environ, "JAX_PLATFORMS": "cpu"})
     assert "8DEV-OK" in r.stdout, r.stdout + r.stderr

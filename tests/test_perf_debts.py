@@ -21,6 +21,10 @@ Plus #6 (PR 10): ``DistEngine.pack_state``'s ``_gather_edges`` pulled
 the stacked edge lanes to host one device_get per array (and per shard
 before that) — the harvest is now ONE fused ``_host_fetch`` of the
 whole lane pytree per save.
+
+Plus #7: every ``DistEngine`` op ran its shard_map body eagerly,
+compiling op by op on every call — ``_spmd`` now compiles each distinct
+program once, with the closures' arrays passed in as arguments.
 """
 import dataclasses
 
@@ -235,3 +239,36 @@ def test_session_apply_syncs_counters_once_per_apply():
     assert eng2.counter_syncs == len(batches) - 1, (
         f"structural apply synced {eng2.counter_syncs}x for "
         f"{len(batches) - 1} batches; want one per apply")
+
+
+# ---------------------------------------------------------------------------
+# #7: one compile per distributed program, not one per call
+# ---------------------------------------------------------------------------
+
+def test_dist_ops_compile_once_per_program():
+    import jax.numpy as jnp
+    from repro.core.dist import DistEngine
+    from repro.graph.csr import INT
+
+    csr = _graph(seed=29)
+    eng = DistEngine()
+    g = eng.prepare(csr, diff_capacity=64)
+    props = {"x": eng.full(0, INT)}
+    batches = list(random_updates(csr, percent=30, seed=6).batches(4))
+    assert len(batches) > 2
+    seen = []
+    for i, b in enumerate(batches):
+        # a fresh closure over a fresh array on every call
+        shift = jnp.full((eng.n_pad,), i + 1, INT)
+        out = eng.vertex_map(g, lambda p: {"x": p["x"] + shift}, props)
+        seen.append(int(np.asarray(out["x"])[0]))
+        g = eng.update_add(eng.update_del(g, b), b)
+    programs = [k for k in eng._stream_cache if k[0] == "spmd"]
+    assert len(programs) == 3, programs      # vertex_map, del, add
+    # closed-over arrays enter as arguments: each call saw its own
+    assert seen == list(range(1, len(batches) + 1))
+    # a host scalar is a literal of the program: a new one is a new
+    # program, never a stale hit
+    for c in (7, 9):
+        out = eng.vertex_map(g, lambda p: {"x": p["x"] + c}, props)
+        assert int(np.asarray(out["x"])[0]) == c
