@@ -310,6 +310,8 @@ class GraphSession:
         self._props: Dict[str, Any] = {}
         # last host-observed overflow counter (see _retry_on_overflow)
         self._of_base = 0
+        # sweep counts of the engines failover left behind
+        self._sweeps_base = (0, 0)
         # ΔG batches applied through apply()/run_stream() — the resume
         # position checkpointed by save()
         self._cursor = 0
@@ -388,10 +390,18 @@ class GraphSession:
         return PropertyView(dict(self._props), self._engine.n_real)
 
     def _sync_counters(self) -> tuple:
-        """ONE host readback of the (overflow, used, dead) pool triple."""
+        """ONE host readback of the (overflow, used, dead) pool triple;
+        the engine's sweep counts ride along into ``health``."""
         _faults.fire("counter_sync", engine=self._backend_name)
-        return tuple(int(x) for x in
-                     np.asarray(self._engine.handle_counters(self._handle)))
+        counters = self._engine.handle_counters(self._handle)
+        sweeps = self._engine.sweep_counts
+        if sweeps is not None:
+            counters = jnp.concatenate([counters, sweeps])
+        host = [int(x) for x in np.asarray(counters)]
+        if sweeps is not None:
+            self._health.sweeps_dense = self._sweeps_base[0] + host[3]
+            self._health.sweeps_sparse = self._sweeps_base[1] + host[4]
+        return tuple(host[:3])
 
     def _n_vertices(self) -> int:
         """Real vertex count, available before AND after prepare (a
@@ -537,6 +547,8 @@ class GraphSession:
         csr, cap = state_to_csr(tree, hmeta)
         engine = make_engine(name)
         handle = engine.prepare(csr, diff_capacity=cap)
+        self._sweeps_base = (self._health.sweeps_dense,
+                             self._health.sweeps_sparse)
         self._engine = engine
         self._handle = handle
         self._backend_name = name

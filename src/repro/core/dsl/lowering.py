@@ -752,8 +752,32 @@ def build_edge_sweep(ex, plan: EdgePlan, frame,
             props["_changed"] = changed
         return props
 
-    return EdgeSweep(edge_fn=edge_fn, reduces=reduces, post_fn=post_fn), \
-        has_changed
+    return EdgeSweep(edge_fn=edge_fn, reduces=reduces, post_fn=post_fn,
+                     frontier=_frontier_prop(plan, frame)), has_changed
+
+
+def _frontier_prop(plan: EdgePlan, frame) -> Optional[str]:
+    """The boolean vertex property a push sweep's outer filter tests
+    (``filter(p == True)`` or ``filter(p)``): only the out-lanes of the
+    vertices that hold it can be eligible, so an engine may sweep those
+    lanes alone."""
+    if plan.orientation != "push":
+        return None
+    f = plan.filter
+    if isinstance(f, A.Binary) and f.op == "==" \
+            and isinstance(f.right, A.Bool) and f.right.value:
+        f = f.left
+    if isinstance(f, A.Attr) and _varname(f.obj) == plan.outer:
+        name = f.name
+    elif isinstance(f, A.Name) and f.ident not in (
+            plan.outer, plan.inner, *plan.edge_vars):
+        name = f.ident
+    else:
+        return None
+    import repro.core.dsl.codegen as CG
+    ref = frame.node_props().get(name)
+    return name if isinstance(ref, CG.PropRef) and ref.elem == "bool" \
+        else None
 
 
 class _SideFilterEnv(dict):
@@ -859,8 +883,7 @@ def run_loop(ex, stmts: List[A.Stmt], frame, kind: str,
             for c in post_closures:
                 props = c(props)
             return props
-        sweep = EdgeSweep(edge_fn=sweep.edge_fn, reduces=sweep.reduces,
-                          post_fn=post_fn, gather_form=sweep.gather_form)
+        sweep = dataclasses.replace(sweep, post_fn=post_fn)
 
     if has_changed:
         extra["_changed"] = jnp.zeros((engine.n_pad,), BOOL)

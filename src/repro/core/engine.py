@@ -78,6 +78,42 @@ def edge_lane_flags(g: DynGraph, qs, qd, mask=None) -> jax.Array:
     return flags
 
 
+# Reductions whose result is the same whichever lanes are left out, so
+# long as those lanes are ineligible, and whatever order the rest are
+# combined in: a frontier-only sweep is then bit-identical to the dense
+# one.  A float ``sum`` is neither.
+_ORDER_FREE = frozenset({"min", "max", "argmin", "or"})
+
+
+def sparse_lane_capacity(main_capacity: int) -> int:
+    """Edge lanes a frontier-only sweep holds: next_pow2(E / 64), so the
+    sparse branch does about a 64th of a dense sweep's lane work."""
+    return 1 << max(main_capacity // 64 - 1, 0).bit_length()
+
+
+def frontier_lanes(g: DynGraph, ends, deg, main_deg, cap: int):
+    """The (esrc, edst, ew, ealive) lanes of the frontier's vertices,
+    padded to ``cap`` with dead lanes.  ``ends`` is the inclusive
+    prefix sum of the frontier's lane counts over the vertices (``deg``
+    where a vertex is in the frontier, else 0), and its last entry is
+    at most ``cap``.  Reads O(n + cap) elements and no (E+D,) array:
+    lane ``j`` belongs to the first vertex whose end exceeds ``j``."""
+    n = g.n
+    lane = jnp.arange(cap, dtype=INT)
+    u = jnp.minimum(jnp.searchsorted(ends, lane, side="right"),
+                    n - 1).astype(INT)
+    r = lane - (ends[u] - deg[u])
+    m_deg = main_deg[u]
+    in_main = r < m_deg
+    mi = jnp.clip(g.offsets[u] + r, 0, g.main_capacity - 1)
+    di = jnp.clip(g.d_offsets[u] + r - m_deg, 0, g.diff_capacity - 1)
+    edst = jnp.where(in_main, g.dst[mi], g.d_dst[di])
+    ew = jnp.where(in_main, g.w[mi], g.d_w[di])
+    ealive = (lane < ends[-1]) & jnp.where(in_main, g.alive[mi],
+                                            g.d_alive[di])
+    return u, edst, ew, ealive
+
+
 class _StreamView:
     """Engine facade handed to stream steps inside ``run_stream``.
 
@@ -125,6 +161,10 @@ class Engine:
     """Backend-neutral interface (the 'generated program' surface)."""
 
     name = "base"
+    # (dense, sparse) fixed-point iterations run so far, on the device;
+    # set by engines that count them (JnpEngine.fixed_point), read by
+    # the session with its per-batch pool-counter readback.
+    sweep_counts: Optional[jax.Array] = None
 
     # -- construction ------------------------------------------------------
     def prepare(self, csr: CSR, diff_capacity: int) -> Any:
@@ -406,6 +446,8 @@ def state_to_csr(tree: Dict[str, Any], meta: dict) -> Tuple[CSR, int]:
 
 class JnpEngine(Engine):
     name = "jnp"
+    # fixed_point may sweep a small frontier's lanes alone (see there)
+    frontier_switch = True
 
     def __init__(self):
         self._n = None
@@ -443,7 +485,14 @@ class JnpEngine(Engine):
 
     # -- core sweep --------------------------------------------------------
     def _run_sweep(self, g: DynGraph, sw: EdgeSweep, props: Props) -> Props:
-        esrc, edst, ew, ealive = g.edge_arrays()
+        return sw.post_fn(props, *self._reduce_lanes(sw, props,
+                                                     *g.edge_arrays()))
+
+    def _reduce_lanes(self, sw: EdgeSweep, props: Props, esrc, edst, ew,
+                      ealive):
+        """The (reduced, hit) of one sweep over the given edge lanes (all
+        E + D of them, or a frontier's); lanes left out must be ones the
+        edge function makes ineligible."""
         n = self.n_pad
         sview = {k: v for k, v in props.items()}
         s = _View(sview, esrc)
@@ -471,24 +520,60 @@ class JnpEngine(Engine):
             v = jnp.where(achieved, esrc, jnp.asarray(n, INT))
             reduced[target] = jax.ops.segment_min(v, edst, num_segments=n)
             hit[target] = hit[of]
-        return sw.post_fn(props, reduced, hit)
+        return reduced, hit
 
     def sweep(self, g: DynGraph, sw: EdgeSweep, props: Props) -> Props:
         return self._run_sweep(g, sw, props)
 
-    def fixed_point(self, g: DynGraph, sw: EdgeSweep, props: Props,
+    def _switch_capacity(self, handle, sw: EdgeSweep) -> int:
+        """Lanes of the sparse branch of ``fixed_point``, or 0 where the
+        sweep keeps the dense branch: no declared frontier, or a
+        reduction whose result depends on the order lanes are combined
+        in (a float ``sum``)."""
+        if not (self.frontier_switch and sw.frontier is not None
+                and all(r.kind in _ORDER_FREE for r in sw.reduces.values())):
+            return 0
+        return sparse_lane_capacity(self.handle_graph(handle).main_capacity)
+
+    def fixed_point(self, g, sw: EdgeSweep, props: Props,
                     cond_fn: Callable, max_iter: int) -> Props:
+        """Sweep to a fixed point in one ``while_loop``.  Where the sweep
+        declares its frontier, each iteration counts the frontier's edge
+        lanes on the device and, when they fit the sparse capacity,
+        sweeps only those lanes (identical results: the other lanes are
+        ineligible, and the reductions are exact and order-free)."""
         col = Collectives()
+        cap = self._switch_capacity(g, sw)
 
         def cond(state):
-            it, p = state
+            it, p, _ = state
             return (it < max_iter) & cond_fn(p, it, col)
 
         def body(state):
-            it, p = state
-            return it + 1, self._run_sweep(g, sw, p)
+            it, p, counts = state
+            if not cap:
+                # an engine's own dense sweep (PallasEngine's ELL kernels)
+                return (it + 1, self._run_sweep(g, sw, p),
+                        counts + jnp.asarray([1, 0], INT))
+            dyn = self.handle_graph(g)
+            main_deg = dyn.offsets[1:] - dyn.offsets[:-1]
+            deg = main_deg + dyn.d_offsets[1:] - dyn.d_offsets[:-1]
+            ends = jnp.cumsum(jnp.where(p[sw.frontier], deg, 0))
+            small = ends[-1] <= cap
+            red = jax.lax.cond(
+                small,
+                lambda p: self._reduce_lanes(sw, p, *frontier_lanes(
+                    dyn, ends, deg, main_deg, cap)),
+                lambda p: self._reduce_lanes(sw, p, *dyn.edge_arrays()), p)
+            p = sw.post_fn(p, *red)
+            return it + 1, p, counts + jnp.stack([~small, small]).astype(INT)
 
-        _, props = jax.lax.while_loop(cond, body, (jnp.zeros((), INT), props))
+        _, props, counts = jax.lax.while_loop(
+            cond, body, (jnp.zeros((), INT), props, jnp.zeros((2,), INT)))
+        # under a trace (a stream scan) the counts are dropped
+        if not isinstance(counts, jax.core.Tracer):
+            prev = self.sweep_counts
+            self.sweep_counts = counts if prev is None else prev + counts
         return props
 
     def vertex_map(self, g: DynGraph, fn: Callable, props: Props) -> Props:

@@ -65,8 +65,9 @@ def _next_pow2(x: int) -> int:
 
 class _DenseStreamView(_StreamView):
     """Stream-scan facade for the FrontierEngine: identical semantics,
-    but fixed points run the fused dense while_loop (jit-safe) instead
-    of the host-driven direction-optimized loop."""
+    but fixed points run JnpEngine's on-device while_loop (jit-safe,
+    with its frontier switch) instead of the host-driven
+    direction-optimized loop."""
 
     def fixed_point(self, h, sw: EdgeSweep, props: Props, cond_fn,
                     max_iter: int) -> Props:

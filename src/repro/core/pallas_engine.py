@@ -62,6 +62,8 @@ class PallasHandle:
 
 class PallasEngine(JnpEngine):
     name = "pallas"
+    # every fixed-point iteration keeps the ELL kernel sweep
+    frontier_switch = False
 
     def __init__(self, k: int = 8, fused: bool = True,
                  autotune: bool = False):

@@ -32,6 +32,11 @@ class SessionHealth:
     kernel_failures: int = 0   # kernel compile/launch failures observed
     # watchdog
     divergence_probes: int = 0
+    # engine: fixed-point iterations over all edge lanes / over a small
+    # frontier's lanes alone, as the engine counted them at the last
+    # counter sync (a pool's tenants share their engine's counts)
+    sweeps_dense: int = 0
+    sweeps_sparse: int = 0
     # identity / last fault
     backend: Optional[str] = None            # currently bound registry name
     preferred_backend: Optional[str] = None  # what bind() originally asked for

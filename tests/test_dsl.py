@@ -182,6 +182,67 @@ def test_dsl_dynamic_tc(engine_cls):
     assert int(res.value) == oracles.tc_oracle(n, e2)
 
 
+class _SweepRecorder(JnpEngine):
+    """Records the EdgeSweep of every fixed point it runs."""
+
+    def __init__(self):
+        super().__init__()
+        self.sweeps = []
+
+    def fixed_point(self, g, sw, props, cond_fn, max_iter):
+        self.sweeps.append(sw)
+        return super().fixed_point(g, sw, props, cond_fn, max_iter)
+
+
+def test_sssp_fixed_points_declare_their_frontier():
+    prog = compile_source(str(PROGS / "sssp.sp"))
+    n, csr, edges, w = random_digraph(seed=11)
+    eng = _SweepRecorder()
+    ups = random_updates(csr, percent=15, seed=2)
+    prog.run("DynSSSP", eng, csr,
+             args={"updateBatch": ups, "batchSize": 8, "src": 0},
+             diff_capacity=64)
+    assert eng.sweeps
+    assert all(sw.frontier == "modified" for sw in eng.sweeps)
+    # each fixedPoint ends in `modified = modified_nxt;` and an
+    # attachNodeProperty, which the lowering folds into a rebuilt
+    # post_fn: the rebuilt sweep keeps its frontier
+    assert all(sw.post_fn.__qualname__.startswith("run_loop.")
+               for sw in eng.sweeps)
+
+
+@pytest.mark.parametrize("spelling,frontier", [
+    ("modified", "modified"),
+    ("v.modified == True", "modified"),
+    ("modified == False", None),
+])
+def test_frontier_follows_the_filter(spelling, frontier):
+    text = (PROGS / "sssp.sp").read_text().replace(
+        "filter(modified == True)", f"filter({spelling})")
+    prog = compile_source(text)
+    n, csr, edges, w = random_digraph(seed=5)
+    eng = _SweepRecorder()
+    prog.run("staticSSSP", eng, csr, args={"src": 0})
+    assert [sw.frontier for sw in eng.sweeps] == [frontier]
+
+
+def test_pagerank_sweeps_declare_no_frontier():
+    """recomputePR and staticPR pull over in-edges with float sums, and
+    propagateNodeFlags builds its own sweep: none declares a frontier."""
+    prog = compile_source(str(PROGS / "pagerank.sp"))
+    n, csr, edges, w = random_digraph(seed=12)
+    eng = _SweepRecorder()
+    ups = random_updates(csr, percent=10, seed=3)
+    prog.run("DynPR", eng, csr,
+             args={"updateBatch": ups, "batchSize": 8,
+                   "beta": 1e-3, "delta": 0.85, "maxIter": 100},
+             diff_capacity=64)
+    kinds = {tuple(sorted(r.kind for r in sw.reduces.values()))
+             for sw in eng.sweeps}
+    assert ("or",) in kinds and ("sum",) in kinds
+    assert all(sw.frontier is None for sw in eng.sweeps)
+
+
 def test_dsl_static_matches_handwritten():
     """DSL-compiled static SSSP ≡ the hand-staged repro.algos version."""
     from repro.algos import sssp as hand
