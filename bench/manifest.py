@@ -6,9 +6,33 @@ piece is a file of its own under ``bench/``:
   configs/<config>.json   the deployment: graph, program, arguments,
                           the limits of the comparison that decides
                           ``correct``; its reference is ``programs/<check>.py``
+  programs/<check>.py     what a run reads back of the program, and its
+                          reference and comparison (below)
   traffic/<traffic>.json  the ΔG stream's parameters, read by ``gen.py``
   metrics/<metric>.py     ``read(obs) -> float | None`` for one metric
   peaks.json              the devices the benchmark knows, by device_kind
+
+A program module provides:
+
+  PROPS                   names of the vertex properties that make the
+                          answer; blocked on after every batch, read back
+                          after the window
+  VALUE                   optional: the name of the Dynamic function's
+                          returned value (``Session.value``), blocked on
+                          with ``PROPS`` after every batch and read back
+                          once after the window as a numpy scalar; where
+                          it is set, ``PROPS`` may be empty
+  run_args(cfg, perm)     the Dynamic function's arguments, the vertices
+                          named in them renamed by the run's permutation
+  reference_props(n, edges, weights, args, control=False)
+                          the reference answer on the reference edge set,
+                          by the same names (``control``: one precision
+                          down, for ``control.py``)
+  compare(got, ref, edges, weights, args)
+                          ``{number: reading}``, each held to the limit of
+                          the same name in the configuration; ``got`` maps
+                          each name of ``PROPS`` and ``VALUE`` to its
+                          read-back
 
 So a later cell is a ``workloads`` entry plus files, and no edit.
 """

@@ -11,19 +11,31 @@ files under ``bench/`` (see ``manifest.py``).  A run:
   2. keeps JAX's compilation cache in ``$JAX_COMPILATION_CACHE_DIR`` or
      else ``bench/.cache/jax``;
   3. makes the graph and the ΔG stream, with vertex labels permuted by
-     ``--seed`` (``gen.py``);
+     ``--seed`` (``gen.py``), and then arms the run's budget (below);
   4. binds the configuration's DSL program through ``repro.compile`` →
      ``bind`` and arms its Dynamic function (prologue = static solve);
   5. warms up on the stream until a batch runs with no pool grow and no
      compile that missed the cache;
   6. drives a closed loop of ``Session.apply`` + ``block_until_ready`` on
-     the repaired properties, starting whole batches while less than
-     ``--seconds`` have passed (``--trace 1``: under the profiler);
-  7. reads the properties and the alive edge set back, frees the session,
+     the repaired answer (the program module's ``PROPS`` and, where it
+     names one, its returned ``VALUE``), starting whole batches while less
+     than ``--seconds`` have passed (``--trace 1``: under the profiler);
+     the budget is disarmed when the window ends;
+  7. reads the answer and the alive edge set back, frees the session,
      and compares them with the numpy reference on the same prefix of the
      stream;
   8. prints the result as the last line of stdout, and each compared
      number beside its limit as the last lines of stderr.
+
+A run's budget bounds the program's share of a run: a set-up (CSR build,
+bind, prologue, warm-up) still running ``SETUP_BUDGET_S`` after the inputs
+are made, or a window batch still running ``WINDOW_BUDGET_S`` after
+``--seconds`` ran out, ends the run with one ``budget:`` line on stderr
+naming the phase, no result line and exit status 1, as a program that
+raises in its set-up ends.  So a program that cannot finish its set-up in
+reasonable time fails instead of running until it is stopped.  Exit
+status: 0 with a result line; 2 and no result without the chip; 1 and no
+result when the program raises or outlives its budget.
 """
 from __future__ import annotations
 
@@ -38,6 +50,7 @@ import json  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
+import threading  # noqa: E402
 import traceback  # noqa: E402
 
 import numpy as np  # noqa: E402
@@ -51,6 +64,17 @@ import trace  # noqa: E402
 
 CACHE = manifest.BENCH / ".cache"
 sys.path.insert(0, str(manifest.ROOT / "src"))
+
+# A run's budget.  Set-up: 6.3 times the longest first-run set-up on
+# record (57.1 s, SSSP, compiles included) and under a third of the
+# 1,200 s that a checkout's first run, which compiles, is allowed; so a
+# program that cannot finish its set-up fails with time to spare.
+SETUP_BUDGET_S = 360.0
+# Window: how long a batch may run past ``--seconds``.  The longest batch
+# on record is about 6.3 s (PageRank), and a 51 s window that holds 5
+# batches allows 10.2 s; 120 s is far past either, and a run that ends
+# there still ends inside the 360 s a run is allowed.
+WINDOW_BUDGET_S = 120.0
 
 
 class NoDevice(RuntimeError):
@@ -117,6 +141,74 @@ def log(*a):
           flush=True)
 
 
+def expire(message: str) -> None:
+    """End a run that outlived its budget: the message on stderr, no
+    result line, exit status 1.  ``os._exit``, since the phase that
+    overran may hold the main thread in a call that never returns."""
+    log(message)
+    os._exit(1)
+
+
+class Watchdog:
+    """A run's budget: one sleeping thread that calls
+    ``on_expire(message)`` when the armed deadline passes before
+    ``disarm``.  The message names the phase running then (``phase``) and
+    the seconds since the budget was armed."""
+
+    def __init__(self, on_expire=expire):
+        self._on_expire = on_expire
+        self._cv = threading.Condition()
+        self._deadline = None          # perf_counter time, None = disarmed
+        self._origin = 0.0
+        self._budget = ""
+        self._phase = "set-up"
+        self._closed = False
+        self._thread = threading.Thread(target=self._watch, daemon=True,
+                                        name="bench-budget")
+        self._thread.start()
+
+    def arm(self, limit_s: float, budget: str) -> None:
+        """Expire ``limit_s`` from now; ``budget`` ends the message, e.g.
+        ``"set-up budget 360 s"``."""
+        with self._cv:
+            self._origin = time.perf_counter()
+            self._deadline = self._origin + limit_s
+            self._budget = budget
+            self._cv.notify()
+
+    def phase(self, name: str) -> None:
+        with self._cv:
+            self._phase = name
+
+    def disarm(self) -> None:
+        with self._cv:
+            self._deadline = None
+            self._cv.notify()
+
+    def close(self) -> None:
+        with self._cv:
+            self._deadline, self._closed = None, True
+            self._cv.notify()
+        self._thread.join()
+
+    def _watch(self) -> None:
+        # ``on_expire`` runs under the lock, so a ``disarm`` racing with it
+        # returns only once it is over (never, for ``expire``)
+        with self._cv:
+            while not self._closed:
+                if self._deadline is None:
+                    self._cv.wait()
+                    continue
+                now = time.perf_counter()
+                if now < self._deadline:
+                    self._cv.wait(self._deadline - now)
+                    continue
+                self._deadline = None
+                self._on_expire(f"budget: {self._phase} still running at "
+                                f"{now - self._origin:.1f} s "
+                                f"({self._budget})")
+
+
 def alive_edges(sess):
     """(edges (E, 2) int64 sorted by (src, dst), weights) alive in the
     session's diff-CSR, read from the packed device state as it is."""
@@ -147,9 +239,11 @@ def edges_wrong(got, want) -> int:
 
 
 def run(cell: manifest.Cell, seed: int, seconds: float, traced: bool,
-        require_tpu: bool = True, fault=None) -> dict:
+        require_tpu: bool = True, fault=None, on_expire=expire) -> dict:
     """One run; returns the result object.  ``fault(sess, batch)`` stands
-    in for ``sess.apply`` (tests plant faults through it)."""
+    in for ``sess.apply`` (tests plant faults through it); ``on_expire``
+    is called with the message if the run outlives its budget (tests
+    record it in place of the process's exit)."""
     import jax
     enable_cache(jax)
     if require_tpu:
@@ -161,14 +255,18 @@ def run(cell: manifest.Cell, seed: int, seconds: float, traced: bool,
     events = Events()
     jax.monitoring.register_event_duration_secs_listener(events.on_duration)
     jax.monitoring.register_event_listener(events.on_event)
+    watchdog = Watchdog(on_expire)
     try:
-        return _run(jax, cell, seed, seconds, traced, device, events, fault)
+        return _run(jax, cell, seed, seconds, traced, device, events, fault,
+                    watchdog)
     finally:
+        watchdog.close()
         jax.monitoring.unregister_event_duration_listener(events.on_duration)
         jax.monitoring.unregister_event_listener(events.on_event)
 
 
-def _run(jax, cell, seed, seconds, traced, device, events, fault) -> dict:
+def _run(jax, cell, seed, seconds, traced, device, events, fault,
+         watchdog) -> dict:
     import repro
     from repro.dsl_programs import path as program_path
     from repro.graph import build_csr
@@ -176,12 +274,20 @@ def _run(jax, cell, seed, seconds, traced, device, events, fault) -> dict:
 
     cfg, traffic, prog = cell.config, cell.traffic, cell.program
     n, edges, weights, stream, perm = gen.make_inputs(cfg, traffic, seed)
+    watchdog.arm(SETUP_BUDGET_S, f"set-up budget {SETUP_BUDGET_S:.0f} s")
     args = prog.run_args(cfg, perm)
     B = stream.batch
     log(f"graph: n={n} edges={len(edges)}; stream: {stream.batches} "
         f"batches of {stream.inserts} inserts + {stream.deletes} deletes "
         f"in {B} lanes")
     apply = fault or (lambda s, b: s.apply(b))
+    value = getattr(prog, "VALUE", None)
+
+    def answer(sess) -> list:
+        """The repaired answer on the device: the properties and, where
+        the program module names it, the function's returned value."""
+        return ([sess.props[p] for p in prog.PROPS]
+                + ([sess.value] if value else []))
 
     def step(sess, k: int) -> int:
         """Stream batch ``k`` through the session; its live lanes."""
@@ -191,23 +297,26 @@ def _run(jax, cell, seed, seconds, traced, device, events, fault) -> dict:
         with jax.profiler.TraceAnnotation("bench.apply"):
             apply(sess, ub)
         with jax.profiler.TraceAnnotation("bench.readback"):
-            jax.block_until_ready([sess.props[p] for p in prog.PROPS])
+            jax.block_until_ready(answer(sess))
         return len(a) + len(d)
 
     # the pool holds one pass of inserts, so the window never grows it;
     # "bind" in the configuration names any other bind argument (backend)
+    watchdog.phase("bind")
     csr = build_csr(n, edges, weights)
     sess = repro.compile(program_path(cfg["program"])).bind(
         csr, capacity=max(stream.batches * stream.inserts, B),
         **cfg.get("bind", {}))
     log("bound")
+    watchdog.phase("prologue")
     sess.run(cfg["function"], batchSize=B, **args)   # prologue; loop armed
-    jax.block_until_ready([sess.props[p] for p in prog.PROPS])
+    jax.block_until_ready(answer(sess))
     log("prologue done")
 
     wu, k = traffic["warmup"], 0
     while True:
         snap, grows = events.snapshot(), sess.health.pool_grows
+        watchdog.phase(f"warm-up batch {k}")
         step(sess, k)
         k += 1
         quiet = (sess.health.pool_grows == grows
@@ -226,8 +335,11 @@ def _run(jax, cell, seed, seconds, traced, device, events, fault) -> dict:
     snap, h0 = events.snapshot(), sess.health.as_dict()
     batches = updates = failed = 0
     t0 = time.perf_counter()
+    watchdog.arm(seconds + WINDOW_BUDGET_S,
+                 f"window budget {seconds:g} s + {WINDOW_BUDGET_S:.0f} s")
     try:
         while time.perf_counter() - t0 < seconds:
+            watchdog.phase(f"window batch {batches}")
             updates += step(sess, k)
             k += 1
             batches += 1
@@ -235,6 +347,7 @@ def _run(jax, cell, seed, seconds, traced, device, events, fault) -> dict:
         failed = 1
         log(f"batch {k} raised:\n{traceback.format_exc()}")
     window_s = time.perf_counter() - t0
+    watchdog.disarm()
     if traced:
         jax.profiler.stop_trace()
     ev = events.since(snap)
@@ -247,6 +360,8 @@ def _run(jax, cell, seed, seconds, traced, device, events, fault) -> dict:
         + json.dumps({e.rsplit("/", 1)[-1]: v for e, v in ev.items()}))
 
     got = {p: sess.props.host(p) for p in prog.PROPS}
+    if value:
+        got[value] = np.asarray(sess.value)[()]
     got_edges = alive_edges(sess)
     del sess, csr
     gc.collect()

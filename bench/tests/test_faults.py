@@ -22,9 +22,14 @@ def small_cell(name):
     return cell
 
 
-def run_small(name, fault=None, seed=2 ** 31 + 17):
-    return run.run(small_cell(name), seed, 1.0, False, require_tpu=False,
-                   fault=fault)
+def run_small(name, fault=None, seed=2 ** 31 + 17, cell=None):
+    """A whole small run; a budget that expires is recorded and fails the
+    test in place of ending the process."""
+    expired = []
+    res = run.run(cell or small_cell(name), seed, 1.0, False,
+                  require_tpu=False, fault=fault, on_expire=expired.append)
+    assert not expired, expired
+    return res
 
 
 def unchanged(sess, batch):
@@ -54,11 +59,17 @@ CELLS = {
 
 
 @pytest.mark.parametrize("name", sorted(CELLS))
-def test_sound_run_is_correct(name):
+def test_sound_run_is_correct(name, monkeypatch):
+    import repro.api
+    values = []
+    value = repro.api.Session.value
+    monkeypatch.setattr(repro.api.Session, "value", property(
+        lambda s: values.append(1) or value.fget(s)))
     res = run_small(name)
     assert res["correct"], res["checks"]
     assert res["attempted"] > 0 and res["failed"] == 0
     assert set(res["metrics"]) == {"updates_per_s", "setup_s"}
+    assert not values, "a module without VALUE never reads Session.value"
 
 
 @pytest.mark.parametrize("name", sorted(CELLS))
